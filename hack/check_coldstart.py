@@ -7,7 +7,7 @@ compile ladder) as a process pair:
 
   * COLD — no compile-cache dir: the restart pays the full on-demand
     compile ladder before its first bind (the pre-PR-15 world);
-  * WARM — KOORD_TPU_COMPILE_CACHE_DIR armed + KOORD_TPU_WARMUP=sync:
+  * WARM — JAX_COMPILATION_CACHE_DIR armed + KOORD_TPU_WARMUP=sync:
     the restart replays the rung index recorded by its own pre-restart
     cycles, XLA compiles disk-served, the first cycle an in-memory
     step-cache HIT.
@@ -49,7 +49,7 @@ def run_crash_restart(env_extra, label):
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("KOORD_TPU_COMPILE_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("KOORD_TPU_WARMUP", None)
     env.update(env_extra)
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
@@ -79,7 +79,7 @@ def run_crash_restart(env_extra, label):
 
 
 def warm_env(cache_dir):
-    return {"KOORD_TPU_COMPILE_CACHE_DIR": cache_dir,
+    return {"JAX_COMPILATION_CACHE_DIR": cache_dir,
             "KOORD_TPU_WARMUP": "sync"}
 
 

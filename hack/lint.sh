@@ -57,7 +57,7 @@ echo "== serial-vs-pipelined + fused-wave + explain + mesh cycle parity =="
 # schedulers in one process and each would redundantly replay the
 # shared rung index; the ladder has its own gate (check_coldstart.py).
 _KOORD_CC_DIR="$(mktemp -d)"
-KOORD_TPU_REPLAY_OVERLAP=1 KOORD_TPU_COMPILE_CACHE_DIR="$_KOORD_CC_DIR" \
+KOORD_TPU_REPLAY_OVERLAP=1 JAX_COMPILATION_CACHE_DIR="$_KOORD_CC_DIR" \
     KOORD_TPU_WARMUP=off JAX_PLATFORMS=cpu \
     python -m koordinator_tpu.scheduler.pipeline_parity
 rm -rf "$_KOORD_CC_DIR"

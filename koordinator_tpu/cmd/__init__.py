@@ -14,7 +14,10 @@ or `--synth NxP` (N nodes, P pods via the synthetic generator). The store
 is in-process — the reference's cross-binary bus is the Kubernetes API
 server, whose analog here is `client.store.ObjectStore`; the all-in-one
 `demo` runs every component against one shared store the way the kind
-cluster wires the reference's binaries to one apiserver.
+cluster wires the reference's binaries to one apiserver. Run as a process
+(`run_binary`), the JAX-using binaries keep their compiled programs in
+JAX_COMPILATION_CACHE_DIR, or in `<checkout>/.jax_compile_cache` when it
+is unset.
 
 state JSON schema (all fields optional):
   {"nodes": [{"name", "cpu": milli, "memory": bytes, "pods": n,
@@ -211,3 +214,18 @@ def run_ticks(tick: Callable[[], object], interval: float, max_ticks: int,
         for sig, handler in previous.items():
             signal.signal(sig, handler)
     return ticks
+
+
+def run_binary(main: Callable[[], int]) -> int:
+    """Process entry of the JAX-using binaries: arm the persistent compile
+    cache (scheduler/warmup.configure_compile_cache — the JAX variable,
+    else the checkout's fixed default) before ``main`` builds anything.
+    Kept out of ``main`` itself so an embedding process (the tests) keeps
+    its own cache setting."""
+    from koordinator_tpu.scheduler.warmup import (
+        configure_compile_cache,
+        default_compile_cache_dir,
+    )
+
+    configure_compile_cache(default_compile_cache_dir())
+    return main()

@@ -32,4 +32,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    from koordinator_tpu.cmd import run_binary
+
+    raise SystemExit(run_binary(main))

@@ -625,16 +625,17 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
                                vmem_budget_bytes=None, kernel: str = "auto",
                                explain=None):
     """Backend-aware selector: the VMEM-resident Pallas kernel on TPU
-    (ops/pallas_full_chain.py, ~20x the fori_loop at 10k x 5k), the XLA
-    step elsewhere. Same contract, bit-identical bindings.
+    (ops/pallas_full_chain.py), the XLA step elsewhere. Same contract,
+    bit-identical bindings.
 
     The Pallas kernel pins all node/NUMA/quota state in VMEM, so its reach
-    is bounded (~20k nodes at R=16, less with NUMA zones and quota groups);
-    past the budget the per-call dispatch degrades to the XLA step instead
-    of failing to compile. The dispatch reads shapes plus one host-side
-    numpy flag (any volumes?), so it never syncs the device; under jit the
-    shape checks fold at trace time and the volume variant stays
-    conservative.
+    is bounded by the device kind's scoped-VMEM limit
+    (ops/pallas_common.VMEM_LIMIT_BYTES; an unknown TPU kind raises): a
+    shape whose estimate exceeds it runs the XLA step, so the selector
+    never dispatches a kernel the chip's compiler refuses. The dispatch
+    reads shapes plus one host-side numpy flag (any volumes?), so it
+    never syncs the device; under jit the shape checks fold at trace time
+    and the volume variant stays conservative.
 
     ``kernel`` forces an implementation: "serial" (XLA fori_loop), "pallas",
     or "wave" (models/wave_chain.py); "auto" is the default selection above.
@@ -688,8 +689,8 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         estimate_vmem_bytes,
     )
 
-    budget = (pc.vmem_budget_bytes() if vmem_budget_bytes is None
-              else vmem_budget_bytes)
+    budget = (pc.vmem_budget_bytes(jax.devices()[0].device_kind)
+              if vmem_budget_bytes is None else vmem_budget_bytes)
     # two lazily-built pallas variants: volume-less batches (the common
     # case) compile out the CSI volume machinery entirely
     pallas_steps = {}
@@ -715,7 +716,7 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         # VMEM budget first: a batch bound for the XLA step anyway must
         # not pay the vol-flag resolution (which can cost a D2H readback
         # for fresh device-resident arrays)
-        if estimate_vmem_bytes(N, R, K, G, P, T, S, PT, SI) > budget:
+        if estimate_vmem_bytes(N, R, K, G, T, S, PT, SI) > budget:
             step.last_backend = "xla"
             return xla_step(fc)
         # the snapshot builder hands HOST (numpy) arrays, so this check

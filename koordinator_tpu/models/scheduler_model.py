@@ -184,10 +184,9 @@ def build_schedule_step(args: LoadAwareArgs, jit: bool = True):
 
 def build_best_schedule_step(args: LoadAwareArgs, vmem_budget_bytes=None):
     """Backend-aware selector: the VMEM-resident Pallas kernel on TPU
-    (ops/pallas_step.py, ~3x the fori_loop at 10k x 5k), the XLA step
-    elsewhere. Same contract, bit-identical bindings. Past the kernel's
-    VMEM budget the per-call dispatch degrades to the XLA step instead of
-    failing to compile (see build_best_full_chain_step)."""
+    (ops/pallas_step.py), the XLA step elsewhere. Same contract,
+    bit-identical bindings. Past the device kind's VMEM budget the
+    per-call dispatch runs the XLA step (see build_best_full_chain_step)."""
     xla_step = build_schedule_step(args)
     if jax.default_backend() != "tpu":
         return xla_step
@@ -197,14 +196,14 @@ def build_best_schedule_step(args: LoadAwareArgs, vmem_budget_bytes=None):
         estimate_vmem_bytes,
     )
 
-    budget = (pc.vmem_budget_bytes() if vmem_budget_bytes is None
-              else vmem_budget_bytes)
+    budget = (pc.vmem_budget_bytes(jax.devices()[0].device_kind)
+              if vmem_budget_bytes is None else vmem_budget_bytes)
     pallas_step = build_pallas_schedule_step(args)
 
     def step(inputs):
-        P, R = inputs.fit_requests.shape
+        R = inputs.fit_requests.shape[1]
         N = inputs.allocatable.shape[0]
-        if estimate_vmem_bytes(N, R, P) <= budget:
+        if estimate_vmem_bytes(N, R) <= budget:
             step.last_backend = "pallas"
             return pallas_step(inputs)
         step.last_backend = "xla"

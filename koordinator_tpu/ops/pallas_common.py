@@ -30,21 +30,41 @@ POD_BLOCK = 128
 # headroom, making (req <= 0) | (req <= free) a single compare.
 NEG_F32 = -3.0e38
 
-# Per-core VMEM the sequential kernels may pin (TPU v4/v5e expose ~16 MiB
-# of VMEM per TensorCore; leave headroom for Mosaic's own spills and the
-# grid machinery). The backend selectors fall back to the XLA step past
-# this. Override with KOORD_TPU_VMEM_BUDGET_BYTES for chips with more VMEM.
-DEFAULT_VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+# Scoped-VMEM limit Mosaic gives one kernel, keyed by jax's
+# ``device_kind``. The backend selectors route a shape to a Pallas kernel
+# only when that kernel's ``estimate_vmem_bytes`` (an upper bound of the
+# compiler's scoped allocation, checked by tests/test_chip_compile.py)
+# fits. An unknown kind is an error: a limit guessed for a chip the code
+# was never compiled for lets the selector dispatch a program the chip's
+# compiler refuses.
+VMEM_LIMIT_BYTES = {
+    # v5e: the compiler's RESOURCE_EXHAUSTED for the full chain at
+    # 2,048 x 6,144 reads "Scoped allocation with size 16.91M and limit
+    # 16.00M" (jax 0.9.0 / libtpu 0.0.34, compiled for a described
+    # v5e:2x2 — PR 21)
+    "TPU v5 lite": 16 * 1024 * 1024,
+}
 
 
-def vmem_budget_bytes() -> int:
-    import os
-
-    raw = os.environ.get("KOORD_TPU_VMEM_BUDGET_BYTES", "")
+def vmem_budget_bytes(device_kind: str) -> int:
     try:
-        return int(raw) if raw else DEFAULT_VMEM_BUDGET_BYTES
-    except ValueError:
-        return DEFAULT_VMEM_BUDGET_BYTES
+        return VMEM_LIMIT_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no scoped-VMEM limit known for TPU kind {device_kind!r}: "
+            "compile the Pallas kernels for it and add its limit to "
+            "ops/pallas_common.VMEM_LIMIT_BYTES") from None
+
+
+def pad8(rows: int) -> int:
+    """Sublane rows an [rows, N] f32 VMEM buffer occupies: one row stays
+    one row, more round up to the 8-row tile."""
+    return 1 if rows <= 1 else -(-rows // 8) * 8
+
+
+def lanes(n: int) -> int:
+    """Lane width an [*, n] buffer occupies (128-lane tiles)."""
+    return -(-n // 128) * 128
 
 
 def weight_consts(weights: np.ndarray) -> List[Tuple[int, float]]:

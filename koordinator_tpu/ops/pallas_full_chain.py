@@ -45,24 +45,33 @@ from koordinator_tpu.ops.numa import POLICY_NONE, POLICY_SINGLE_NUMA_NODE
 from koordinator_tpu.ops.pallas_common import POD_BLOCK, UNROLL
 
 
-def estimate_vmem_bytes(N: int, R: int, K: int, G: int, P: int,
-                        T: int = 0, S: int = 0, PT: int = 0,
-                        SI: int = 0) -> int:
-    """Upper-bound VMEM footprint of one pallas_call of the full-chain
-    kernel, mirroring the in/out/scratch specs below: 3 double-buffered
-    [R, POD_BLOCK] pod column blocks, 8 [R, N] node buffers, 2 [K*R, N]
-    NUMA buffers, 11 [1, N] rows, quota state (4 [R, G_lane] + the
-    double-buffered [UNROLL, G_lane] ancestor blocks) and the chosen
-    output, all f32. Used by models.full_chain.build_best_full_chain_step
-    to fall back to the XLA step when the state would not fit on-chip."""
-    P_pad = -(-P // POD_BLOCK) * POD_BLOCK
-    G_eff = max(G, 1)
-    G_lane = max(128, -(-G_eff // 128) * 128)
-    floats = (3 * POD_BLOCK * R * 2 + 8 * R * N + 2 * K * R * N + 14 * N
-              + 5 * max(T, 0) * N + max(S, 1) * N
-              + 2 * max(PT, 1) * N + max(SI, 1) * N
-              + 4 * R * G_lane + 2 * UNROLL * G_lane + P_pad)
-    return 4 * floats
+def estimate_vmem_bytes(N: int, R: int, K: int, G: int, T: int = 0,
+                        S: int = 0, PT: int = 0, SI: int = 0) -> int:
+    """Upper bound of the scoped VMEM the compiler allocates for one
+    pallas_call of the full-chain kernel (volumes on or off), mirroring
+    the specs below: every blocked input and output is double-buffered,
+    scratch is single; an [r, N] buffer takes pad8(r) rows of lanes(N);
+    each NUMA zone slice that starts off an 8-row tile costs a relayout of
+    2 * pad8(R) rows; 24 rows cover the other body temporaries. Fitted
+    against the v5e compiler's reported allocation over R in 3..16, K in
+    1..8 and 1k..8k nodes (PR 21: within 0-7% above it where R and the
+    zone slices are tile-aligned, more above it where they are not).
+    Independent of the pod count: pod columns stream in fixed blocks.
+    Used by
+    models.full_chain.build_best_full_chain_step to keep a shape whose
+    state would not fit on the XLA step."""
+    Rp, KR = pc.pad8(R), pc.pad8(K * R)
+    extras_in = 3 * pc.pad8(T) + pc.pad8(S) + pc.pad8(PT) + pc.pad8(SI)
+    rows_in = 4 * Rp + KR + 11 + extras_in
+    rows_scratch = 3 * Rp + KR + 2 + 2 * pc.pad8(T) + pc.pad8(PT)
+    misaligned = sum(1 for k in range(1, K) if (k * R) % 8)
+    rows = (2 * rows_in + 2 * Rp + rows_scratch
+            + 2 * Rp * misaligned + 24 + 4)  # + 4: the volume rows' temps
+    G_lane = max(128, pc.lanes(max(G, 1)))
+    fixed = (2 * 3 * Rp * POD_BLOCK               # pod column blocks
+             + 7 * Rp * G_lane + 2 * UNROLL * G_lane  # quota state
+             + 2 * UNROLL * 128)                  # chosen blocks
+    return 4 * (rows * pc.lanes(N) + fixed)
 
 
 # measured Mosaic SMEM allocation limit on v5e (the compile error reports

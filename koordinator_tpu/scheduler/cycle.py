@@ -594,8 +594,9 @@ class Scheduler:
         self.pack_overlap = (pack_overlap_from_env()
                              if pack_overlap is None else bool(pack_overlap))
         # persistent compile cache + warm-up (scheduler/warmup.py):
-        # KOORD_TPU_COMPILE_CACHE_DIR arms jax's on-disk executable
-        # cache and the rung index; the warm-up ladder (started at the
+        # JAX_COMPILATION_CACHE_DIR (or a process entry point's
+        # default dir) arms jax's on-disk executable cache and the rung
+        # index; the warm-up ladder (started at the
         # END of construction, once the mesh/transformers are final)
         # replays recorded rungs so a restarted scheduler's first cycle
         # is an in-memory step-cache hit.
@@ -866,7 +867,7 @@ class Scheduler:
 
     def _record_step_compile(self, kind: str, meta: Dict, args: Tuple) -> None:
         """Record a freshly-compiled rung into the persistent warm-up
-        index (no-op without KOORD_TPU_COMPILE_CACHE_DIR). Best-effort
+        index (no-op while no compile-cache dir is configured). Best-effort
         by contract — the index is for the NEXT process."""
         if self.compile_cache_dir is None:
             return
@@ -2593,6 +2594,10 @@ class Scheduler:
                                 fc, np.int32(len(nodes.names)))
                         else:
                             chosen, _, _ = step(fc)  # async — no sync
+                        # which kernel the selector dispatched (pallas /
+                        # xla; a mesh step is the sharded XLA step)
+                        ksp.attributes["backend"] = str(
+                            getattr(step, "last_backend", None) or "xla")
                         if self.pipeline_mode:
                             # overlap window: the previous cycle's
                             # deferred host work (unschedulability

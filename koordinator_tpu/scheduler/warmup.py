@@ -5,13 +5,18 @@ pays the full XLA compile ladder before its first bind (43.2s cold cycle
 at 100k x 50k, MULTICHIP_r06; 1.53s restart-to-first-bind wall even at
 sim scale, CHURN_r03). Two layers close it:
 
-  1. **Persistent XLA compilation cache** — ``KOORD_TPU_COMPILE_CACHE_DIR``
-     arms jax's on-disk executable cache (``jax_compilation_cache_dir``)
-     so a re-traced program whose HLO already compiled in ANY prior
-     process deserializes instead of recompiling. The thresholds are
-     pinned to cache-everything: the scheduler's programs are exactly the
-     multi-second compiles the cache exists for, and on the CPU backend
-     the default min-compile-time threshold would skip the small rungs.
+  1. **Persistent XLA compilation cache** — ``JAX_COMPILATION_CACHE_DIR``
+     (jax's own variable) arms jax's on-disk executable cache so a
+     re-traced program whose HLO already compiled in ANY prior process
+     deserializes instead of recompiling. When it is set, no other
+     directory is ever configured. The process entry points (the
+     ``cmd/`` binaries, ``chip_smoke.py``) fall back to
+     :func:`default_compile_cache_dir` — a fixed path, because the path is
+     part of the cache key — while a library ``Scheduler()`` with the
+     variable unset stays cache-off (the determinism tests' default).
+     The thresholds are pinned to cache every step program: on the CPU
+     backend the default min-compile-time threshold would skip the small
+     rungs.
 
   2. **Warm-up ladder** (:class:`WarmupRunner`) — every step compile the
      cycle driver (and the rebalance/colo passes) performs is recorded in
@@ -86,11 +91,19 @@ _FINGERPRINT_PACKAGES = ("models", "ops", "parallel", "balance", "colo")
 
 
 def compile_cache_dir_from_env() -> Optional[str]:
-    """KOORD_TPU_COMPILE_CACHE_DIR=<dir> arms the persistent compile
-    cache + the warm-up index; unset/empty keeps both off (the
-    pre-PR-15 behavior, and the deterministic default for tests)."""
-    raw = os.environ.get("KOORD_TPU_COMPILE_CACHE_DIR", "").strip()
+    """``JAX_COMPILATION_CACHE_DIR=<dir>`` arms the persistent compile
+    cache + the warm-up index; unset/empty keeps both off for a library
+    ``Scheduler()`` (the deterministic default for tests)."""
+    raw = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
     return raw or None
+
+
+def default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_compile_cache`` (listed in .gitignore): the fixed
+    cache path of the process entry points when
+    ``JAX_COMPILATION_CACHE_DIR`` is unset."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_compile_cache")
 
 
 def warmup_mode_from_env() -> str:
@@ -116,14 +129,16 @@ _configured_dir: Optional[str] = None
 
 
 def configure_compile_cache(dir_path: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``dir_path`` (default:
-    the env knob). Idempotent and process-global — jax's cache config is
-    global, so the first caller wins and later calls with the same dir
-    are no-ops (a different dir logs and keeps the first: two schedulers
-    in one process must share one cache). Returns the effective dir, or
-    None when the cache stays off."""
+    """Point jax's persistent compilation cache at
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (``dir_path`` is then
+    ignored), else at ``dir_path``. Idempotent and process-global — jax's
+    cache config is global, so the first caller wins and later calls are
+    no-ops (a different dir logs and keeps the first: two schedulers in
+    one process must share one cache). Returns the effective dir, or None
+    when the cache stays off."""
     global _configured_dir
-    want = dir_path if dir_path is not None else compile_cache_dir_from_env()
+    env_dir = compile_cache_dir_from_env()
+    want = env_dir if env_dir is not None else dir_path
     if want is None:
         return _configured_dir
     if _configured_dir is not None:
@@ -136,6 +151,8 @@ def configure_compile_cache(dir_path: Optional[str] = None) -> Optional[str]:
     os.makedirs(want, exist_ok=True)
     import jax
 
+    # jax read JAX_COMPILATION_CACHE_DIR when it was imported; setting
+    # the same value again covers a variable exported after that import
     jax.config.update("jax_compilation_cache_dir", want)
     # threshold discipline: every STEP program (>= hundreds of ms even at
     # sim scale) must cache, so the default 1s min-compile-time is
@@ -146,12 +163,8 @@ def configure_compile_cache(dir_path: Optional[str] = None) -> Optional[str]:
     # keeps every rung the coldstart gate measures while leaving the
     # tiny jits to compile fresh — the determinism gates (lint parity +
     # sim --check-determinism) run with the cache armed to pin this.
-    for flag, value in (("jax_persistent_cache_min_compile_time_secs", 0.1),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, value)
-        except Exception:  # older jax without the knob: dir alone works
-            logger.debug("jax flag %s unavailable", flag)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _configured_dir = want
     return want
 

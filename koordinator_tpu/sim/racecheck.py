@@ -557,7 +557,7 @@ def run_racecheck(preempt_seed: int = 0, cycles: int = 24,
 
     Env during the run: ``KOORD_TPU_WARMUP=background`` (the warm-up
     ladder races the first cycles for real) and a throwaway
-    ``KOORD_TPU_COMPILE_CACHE_DIR`` (so the background ladder has an
+    ``JAX_COMPILATION_CACHE_DIR`` (so the background ladder has an
     index to record into); both restored after."""
     import shutil
     import tempfile
@@ -568,10 +568,10 @@ def run_racecheck(preempt_seed: int = 0, cycles: int = 24,
     sc = scenario if scenario is not None else racecheck_scenario(cycles)
 
     saved_env = {k: os.environ.get(k)
-                 for k in ("KOORD_TPU_WARMUP", "KOORD_TPU_COMPILE_CACHE_DIR")}
+                 for k in ("KOORD_TPU_WARMUP", "JAX_COMPILATION_CACHE_DIR")}
     cache_dir = tempfile.mkdtemp(prefix="koordrace-cache-")
     os.environ["KOORD_TPU_WARMUP"] = "background"
-    os.environ["KOORD_TPU_COMPILE_CACHE_DIR"] = cache_dir
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
 
     scrape_errors: List[str] = []
     scrape_count = [0]

@@ -304,7 +304,7 @@ class TestWarmupRacesFirstCycle:
             configure_compile_cache,
         )
 
-        monkeypatch.setenv("KOORD_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         cache_dir = configure_compile_cache()
         if cache_dir is None:  # pragma: no cover - config is first-wins
             pytest.skip("compile cache unavailable in this process")

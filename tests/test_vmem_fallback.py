@@ -19,29 +19,36 @@ from koordinator_tpu.scheduler.snapshot import build_full_chain_inputs
 from koordinator_tpu.testing import synth_full_cluster
 
 
+V5E = pc.VMEM_LIMIT_BYTES["TPU v5 lite"]
+
+
 class TestEstimates:
     def test_flagship_shape_fits_default_budget(self):
         # the headline bench config (10k pods x 5k nodes, R=16, K=2,
-        # G=64) must stay on the Pallas path
-        assert fc_vmem(5_000, 16, 2, 64, 10_000) <= pc.DEFAULT_VMEM_BUDGET_BYTES
-        assert step_vmem(5_000, 16, 10_000) <= pc.DEFAULT_VMEM_BUDGET_BYTES
+        # G=64) must stay on the Pallas path, and so must BASELINE config
+        # 4 with no axis reduction (R=14, K=8), which the v5e compiler
+        # accepts at 14.1 MiB (tests/test_chip_compile.py)
+        assert fc_vmem(5_000, 16, 2, 64) <= V5E
+        assert fc_vmem(5_120, 14, 8, 8) <= V5E
+        assert step_vmem(5_000, 16) <= V5E
 
     def test_50k_nodes_exceeds_default_budget(self):
-        assert fc_vmem(50_000, 16, 2, 64, 10_000) > pc.DEFAULT_VMEM_BUDGET_BYTES
+        assert fc_vmem(50_000, 16, 2, 64) > V5E
+        # the v5e compiler refuses R=14, K=8 at 6,144 nodes (16.91 MiB)
+        assert fc_vmem(6_144, 14, 8, 8) > V5E
 
     def test_monotonic_in_every_dim(self):
-        base = fc_vmem(1_000, 16, 2, 64, 2_000)
-        assert fc_vmem(2_000, 16, 2, 64, 2_000) > base
-        assert fc_vmem(1_000, 32, 2, 64, 2_000) > base
-        assert fc_vmem(1_000, 16, 4, 64, 2_000) > base
-        assert fc_vmem(1_000, 16, 2, 300, 2_000) > base
-        assert fc_vmem(1_000, 16, 2, 64, 4_000) > base
+        base = fc_vmem(1_000, 16, 2, 64)
+        assert fc_vmem(2_000, 16, 2, 64) > base
+        assert fc_vmem(1_000, 32, 2, 64) > base
+        assert fc_vmem(1_000, 16, 4, 64) > base
+        assert fc_vmem(1_000, 16, 2, 300) > base
+        assert fc_vmem(1_000, 16, 2, 64, T=4) > base
 
-    def test_env_override(self, monkeypatch):
-        monkeypatch.setenv("KOORD_TPU_VMEM_BUDGET_BYTES", "123456")
-        assert pc.vmem_budget_bytes() == 123456
-        monkeypatch.setenv("KOORD_TPU_VMEM_BUDGET_BYTES", "not-a-number")
-        assert pc.vmem_budget_bytes() == pc.DEFAULT_VMEM_BUDGET_BYTES
+    def test_budget_table_rejects_unknown_kinds(self):
+        assert pc.vmem_budget_bytes("TPU v5 lite") == 16 * 1024 * 1024
+        with pytest.raises(ValueError, match="no scoped-VMEM limit"):
+            pc.vmem_budget_bytes("TPU v99")
 
 
 class TestDispatch:

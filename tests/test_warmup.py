@@ -220,3 +220,38 @@ class TestWarmupLadder:
         sched.compile_miss_hook = flagged.append
         assert sched.run_cycle(now=state.now).bound
         assert flagged == []
+
+
+_CACHE_PROBE = """
+import jax
+from koordinator_tpu.scheduler import warmup as wu
+from koordinator_tpu.scheduler.cycle import Scheduler
+from koordinator_tpu.client.store import ObjectStore
+lib = Scheduler(ObjectStore()).compile_cache_dir
+got = wu.configure_compile_cache(wu.default_compile_cache_dir())
+print(lib, got, jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_cache_dir_rule(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and no other dir is set; unset, a
+    library Scheduler stays cache-off and the entry points fall back to
+    <checkout>/.jax_compile_cache. A fresh process: jax's cache config is
+    process-global."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, check=True,
+                         timeout=120).stdout.split()
+    default = os.path.join(root, ".jax_compile_cache")
+    if env_dir:
+        assert out == [str(tmp_path)] * 3
+    else:
+        assert out == ["None", default, default]
