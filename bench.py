@@ -1412,9 +1412,7 @@ def run_mesh_sweep(args_cli) -> None:
                 else (0 if args_cli.smoke else 1))
     if scale_on and devices:
         from koordinator_tpu.client.store import KIND_POD
-        from koordinator_tpu.scheduler.pipeline_parity import (
-            top_up_running_pods,
-        )
+        from koordinator_tpu.testing import top_up_running_pods
 
         d = max(devices)
         nn, np_, target = 50_000, 2_048, 100_000

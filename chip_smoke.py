@@ -25,7 +25,8 @@ one informational line, and any failed check exits non-zero):
              per-sync cost the fused-wave design amortizes.
   mesh     — (--chips 4 only) Scheduler(store, mesh=4) on the 100k pods x
              50k nodes mesh-scale world (2,048 pending) against a
-             single-device twin on an identical store.
+             single-device twin on an identical store, with the served
+             phase's ladder, retry, demotion and host-fallback checks.
 
 Timings printed here are bring-up observations, not benchmark metrics.
 The last stdout line is exactly
@@ -178,9 +179,11 @@ def _store_world(num_nodes: int, num_pods: int, seed: int,
                  total_pods: int = 0):
     from koordinator_tpu.scheduler.pipeline_parity import (
         build_store_from_state,
+    )
+    from koordinator_tpu.testing import (
+        synth_full_cluster,
         top_up_running_pods,
     )
-    from koordinator_tpu.testing import synth_full_cluster
 
     _cluster, state = synth_full_cluster(
         num_nodes, num_pods, seed=seed,
@@ -222,6 +225,19 @@ def _host_fallback_ran(sched) -> bool:
                for ks in root.find_all("kernel"))
 
 
+def _warmup_summary(sched) -> str:
+    """The background ladder's outcome (its zero-calls count in
+    compile_s): rungs by outcome and seconds, or "off"."""
+    if sched.warmup is None:
+        return "off"
+    sched.warmup.join()
+    st = sched.warmup.stats
+    return ",".join(
+        [f"{k}={st[k]}" for k in ("rungs", "warmed", "built", "skipped",
+                                  "failed", "invalidated")]
+        + [f"s={st['seconds']:.3f}"])
+
+
 def _dispatch_retries() -> float:
     from koordinator_tpu.scheduler import metrics as scheduler_metrics
 
@@ -230,17 +246,21 @@ def _dispatch_retries() -> float:
 
 def phase_served(num_pods: int = 10_000, num_nodes: int = 5_000,
                  cycles: int = 3, arrivals: int = 1_000, seed: int = 0,
-                 fault_injector=None) -> dict:
+                 fault_injector=None, warmup: str = "off") -> dict:
     """Scheduler(store) with every default on vs the serial reference
     twin. ``fault_injector`` (tests) is installed on the scheduler under
-    test; an absorbed fault must fail the phase."""
+    test; an absorbed fault must fail the phase. ``warmup`` is the
+    scheduler under test's warm-up mode: main() runs the ladder in the
+    background, as a restart with a compile cache does (a test must not,
+    since the cache dir is process-global); the twin is the reference
+    and never runs one, so it never competes with cycle 0."""
     from koordinator_tpu.scheduler.cycle import Scheduler
     from koordinator_tpu.scheduler.pipeline_parity import apply_round_delta
 
     state, store = _store_world(num_nodes, num_pods, seed)
     _state, store_twin = _store_world(num_nodes, num_pods, seed)
-    sched = Scheduler(store)
-    twin = Scheduler(store_twin, waves=1)
+    sched = Scheduler(store, warmup=warmup)
+    twin = Scheduler(store_twin, waves=1, warmup="off")
     sched.fault_injector = fault_injector
     retries0 = _dispatch_retries()
     now = state.now
@@ -283,6 +303,7 @@ def phase_served(num_pods: int = 10_000, num_nodes: int = 5_000,
         "cycle0_s": round(walls[0], 3),
         "steady_cycle_s": (round(statistics.median(walls[1:]), 4)
                            if len(walls) > 1 else None),
+        "warmup": _warmup_summary(sched),
     }
     say("served", **info)
     return info
@@ -306,19 +327,23 @@ def phase_sync(iters: int = 20) -> dict:
 
 
 def phase_mesh(ndev: int = 4, num_nodes: int = 50_000, pending: int = 2_048,
-               total_pods: int = 100_000, cycles: int = 1, seed: int = 7
-               ) -> dict:
+               total_pods: int = 100_000, cycles: int = 1, seed: int = 7,
+               fault_injector=None, warmup: str = "off") -> dict:
     """Mesh dispatch over ``ndev`` devices vs a single-device twin on an
-    identical store (the MULTICHIP_r06 mesh-scale world)."""
+    identical store (the MULTICHIP_r06 mesh-scale world).
+    ``fault_injector`` and ``warmup`` apply to the mesh scheduler, as in
+    phase_served."""
     from koordinator_tpu.scheduler.cycle import Scheduler
     from koordinator_tpu.scheduler.pipeline_parity import apply_round_delta
 
     state, store_m = _store_world(num_nodes, pending, seed, total_pods)
     _state, store_1 = _store_world(num_nodes, pending, seed, total_pods)
-    sched_m = Scheduler(store_m, mesh=ndev)
-    sched_1 = Scheduler(store_1, mesh="off")
+    sched_m = Scheduler(store_m, mesh=ndev, warmup=warmup)
+    sched_1 = Scheduler(store_1, mesh="off", warmup="off")
+    sched_m.fault_injector = fault_injector
     check(sched_m.mesh is not None and sched_m.mesh.devices.size == ndev,
           f"mesh={ndev} did not resolve to a {ndev}-device mesh")
+    retries0 = _dispatch_retries()
     now = state.now
     walls_m, walls_1, bound = [], [], []
     for r in range(cycles + 1):
@@ -334,6 +359,9 @@ def phase_mesh(ndev: int = 4, num_nodes: int = 50_000, pending: int = 2_048,
         t0 = time.perf_counter()
         res_1 = sched_1.run_cycle(now=t)
         walls_1.append(time.perf_counter() - t0)
+        for res in (res_m, res_1):
+            check(res.demotions == [],
+                  f"mesh cycle {r} demoted: {res.demotions}")
         got = [(b.pod_key, b.node_name) for b in res_m.bound]
         want = [(b.pod_key, b.node_name) for b in res_1.bound]
         check(got, f"mesh cycle {r} bound no pod")
@@ -349,6 +377,9 @@ def phase_mesh(ndev: int = 4, num_nodes: int = 50_000, pending: int = 2_048,
     for sched in (sched_m, sched_1):
         check(sched.ladder.level_name == "full",
               f"ladder at {sched.ladder.level_name!r}, not 'full'")
+        check(not _host_fallback_ran(sched), "the host-fallback pass ran")
+    retries = _dispatch_retries() - retries0
+    check(retries == 0, f"{retries:g} dispatch retries")
     info = {
         "world": f"{total_pods or pending}x{num_nodes}",
         "pending": pending,
@@ -359,6 +390,8 @@ def phase_mesh(ndev: int = 4, num_nodes: int = 50_000, pending: int = 2_048,
         "shard_rows": rows[:ndev],
         "mesh_cycle_s": [round(w, 3) for w in walls_m],
         "single_cycle_s": [round(w, 3) for w in walls_1],
+        "retries": int(retries),
+        "warmup": _warmup_summary(sched_m),
     }
     say("mesh", **info)
     return info
@@ -420,10 +453,10 @@ def main(argv=None) -> int:
         cache_dir = configure_compile_cache(default_compile_cache_dir())
         cache = _count_cache_events()
         if args.chips == 4:
-            phase_mesh(ndev=4)
+            phase_mesh(ndev=4, warmup="background")
         else:
             phase_kernels()
-            phase_served()
+            phase_served(warmup="background")
             phase_sync()
         say("cache", dir=cache_dir, **cache)
     except SmokeFailure as e:

@@ -689,8 +689,8 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         estimate_vmem_bytes,
     )
 
-    budget = (pc.vmem_budget_bytes(jax.devices()[0].device_kind)
-              if vmem_budget_bytes is None else vmem_budget_bytes)
+    budget = (pc.default_vmem_budget() if vmem_budget_bytes is None
+              else vmem_budget_bytes)
     # two lazily-built pallas variants: volume-less batches (the common
     # case) compile out the CSI volume machinery entirely
     pallas_steps = {}

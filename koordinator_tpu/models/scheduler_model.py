@@ -196,8 +196,8 @@ def build_best_schedule_step(args: LoadAwareArgs, vmem_budget_bytes=None):
         estimate_vmem_bytes,
     )
 
-    budget = (pc.vmem_budget_bytes(jax.devices()[0].device_kind)
-              if vmem_budget_bytes is None else vmem_budget_bytes)
+    budget = (pc.default_vmem_budget() if vmem_budget_bytes is None
+              else vmem_budget_bytes)
     pallas_step = build_pallas_schedule_step(args)
 
     def step(inputs):

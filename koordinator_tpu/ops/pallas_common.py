@@ -9,7 +9,7 @@ the kernel bodies, so a fix lands in both at once.
 from __future__ import annotations
 
 import functools
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,14 @@ def vmem_budget_bytes(device_kind: str) -> int:
             f"no scoped-VMEM limit known for TPU kind {device_kind!r}: "
             "compile the Pallas kernels for it and add its limit to "
             "ops/pallas_common.VMEM_LIMIT_BYTES") from None
+
+
+def default_vmem_budget() -> Optional[int]:
+    """The scoped-VMEM budget of jax's default device: None off TPU; an
+    unknown TPU kind raises (vmem_budget_bytes)."""
+    if jax.default_backend() != "tpu":
+        return None
+    return vmem_budget_bytes(jax.devices()[0].device_kind)
 
 
 def pad8(rows: int) -> int:

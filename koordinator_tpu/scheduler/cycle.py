@@ -501,6 +501,17 @@ class Scheduler:
         self.config = (_dc.replace(base, load_aware=args)
                        if args is not None else base)
         self.config.validate()
+        # the Pallas selector's scoped-VMEM budget, looked up here and not
+        # in _get_step: an unknown TPU kind must fail construction, not
+        # raise inside the dispatch ladder, which would retry and demote
+        # the device away while pods keep binding
+        import jax
+
+        self._vmem_budget = None
+        if jax.default_backend() == "tpu":
+            from koordinator_tpu.ops import pallas_common
+
+            self._vmem_budget = pallas_common.default_vmem_budget()
         self.store = store
         self.args = self.config.load_aware
         self.scheduler_name = scheduler_name
@@ -1199,7 +1210,7 @@ class Scheduler:
             else:
                 step = build_best_full_chain_step(
                     self.args, ng, ngroups, active_axes=active,
-                    explain=explain)
+                    vmem_budget_bytes=self._vmem_budget, explain=explain)
         self._add_compile_wall(csp.duration_seconds)
         with self._step_lock:
             self._step_cache[key] = step

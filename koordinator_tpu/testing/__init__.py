@@ -9,4 +9,5 @@ from koordinator_tpu.testing.synth import (  # noqa: F401
     SynthCluster,
     synth_cluster,
     synth_full_cluster,
+    top_up_running_pods,
 )

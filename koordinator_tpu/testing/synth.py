@@ -331,3 +331,23 @@ def synth_full_cluster(
         now=cluster.now,
     )
     return cluster, state
+
+
+def top_up_running_pods(store, target: int, num_nodes: int,
+                        namespace: str = "meshscale") -> None:
+    """Deterministic Running filler pods up to ``target`` pods in the
+    store, round-robin over ``node-0..node-{num_nodes-1}``: running pods
+    only shape the per-node requested sums (the incremental pack memo
+    aggregates them) — the 100k-pod mesh-scale world's host-side load."""
+    from koordinator_tpu.client.store import KIND_POD
+
+    have = len(store.list(KIND_POD))
+    for i in range(max(0, target - have)):
+        store.add(KIND_POD, Pod(
+            meta=ObjectMeta(name=f"filler-{i}", namespace=namespace,
+                            uid=f"filler-{i}"),
+            spec=PodSpec(
+                node_name=f"node-{i % num_nodes}",
+                requests=ResourceList.of(
+                    cpu=50, memory=64 * MIB, pods=1)),
+            phase="Running"))

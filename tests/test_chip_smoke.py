@@ -3,7 +3,7 @@
 The script itself only runs on a TPU; here each phase function runs on the
 session's CPU backend, with the Pallas interpreter chosen by the test (the
 script never interprets). main() must refuse a non-TPU platform, and an
-absorbed dispatch fault must fail the served-path phase.
+absorbed dispatch fault must fail the served-path and mesh phases.
 """
 
 import os
@@ -33,17 +33,22 @@ def test_served_phase_matches_serial_twin():
     assert info["kernels"][0].startswith("chain(")
 
 
-def test_served_phase_fails_on_absorbed_fault():
+def _fault_once():
     fired = []
 
-    def fault_once(stage):
+    def inject(stage):
         if not fired:
             fired.append(stage)
             raise RuntimeError("injected dispatch fault")
 
+    return fired, inject
+
+
+def test_served_phase_fails_on_absorbed_fault():
+    fired, inject = _fault_once()
     with pytest.raises(chip_smoke.SmokeFailure, match="dispatch retries"):
         chip_smoke.phase_served(num_pods=120, num_nodes=40, cycles=0,
-                                fault_injector=fault_once)
+                                fault_injector=inject)
     assert fired
 
 
@@ -58,7 +63,19 @@ def test_mesh_phase_on_four_virtual_devices(cpu_devices):
     info = chip_smoke.phase_mesh(ndev=4, num_nodes=200, pending=150,
                                  total_pods=600)
     assert info["mesh_devices"] == 4 and info["bindings"] == "mesh=single"
+    assert info["retries"] == 0
     assert all(n > 0 for n in info["shard_rows"])
+
+
+def test_mesh_phase_fails_on_absorbed_fault(cpu_devices):
+    if len(cpu_devices) < 4:
+        pytest.skip("needs 4 virtual CPU devices")
+    fired, inject = _fault_once()
+    with pytest.raises(chip_smoke.SmokeFailure, match="dispatch retries"):
+        chip_smoke.phase_mesh(ndev=4, num_nodes=200, pending=150,
+                              total_pods=600, cycles=0,
+                              fault_injector=inject)
+    assert fired
 
 
 def test_main_refuses_a_non_tpu_platform(monkeypatch, capsys):

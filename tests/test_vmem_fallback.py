@@ -144,3 +144,24 @@ def test_volume_less_high_vg_batch_stays_on_pallas_budget():
 
     assert estimate_smem_bytes(10_000, VG=0, T=8) <= SMEM_BUDGET_BYTES
     assert estimate_smem_bytes(10_000, VG=16, T=8) > SMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v99"])
+def test_scheduler_looks_up_the_budget_at_construction(monkeypatch, kind):
+    """The budget is looked up when the Scheduler is built: an unknown TPU
+    kind fails there, before any cycle, and never reaches the dispatch
+    ladder (which would retry and demote it to host-fallback)."""
+    import types
+
+    from koordinator_tpu.client.store import ObjectStore
+    from koordinator_tpu.scheduler.cycle import Scheduler
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(device_kind=kind)])
+    if kind in pc.VMEM_LIMIT_BYTES:
+        sched = Scheduler(ObjectStore(), mesh="off", warmup="off")
+        assert sched._vmem_budget == pc.VMEM_LIMIT_BYTES[kind]
+    else:
+        with pytest.raises(ValueError, match="no scoped-VMEM limit"):
+            Scheduler(ObjectStore(), mesh="off", warmup="off")
