@@ -540,14 +540,10 @@ def run_sim_churn(args_cli, scenario) -> None:
     deterministic = a.binding_log == b.binding_log
     log(f"binding logs {'IDENTICAL' if deterministic else 'DIVERGED'} "
         f"across the pair (sha256 {a.binding_log_sha256[:16]})")
-    # occupancy + per-K throughput under REALISTIC arrivals (not the
-    # synthetic 2%-delta loop): both runs of the pair, so the occupancy
-    # number itself is citable as a back-to-back pair
+    # per-K throughput under REALISTIC arrivals (not the synthetic
+    # 2%-delta loop)
     a_dict = a.to_dict()  # built once: each call rebuilds the SLO math
-    occ_pair = [a_dict["pipeline"]["occupancy"],
-                b.to_dict()["pipeline"]["occupancy"]]
-    log(f"pipeline occupancy (pair): {occ_pair[0]:.3f} / {occ_pair[1]:.3f}; "
-        f"pods/s by consumed waves: "
+    log(f"pods/s by consumed waves: "
         f"{a_dict['pipeline']['pods_per_sec_at_k']}")
     print(json.dumps({
         "metric": f"churn_bound_pods_per_sec_{sc.name}",
@@ -558,8 +554,6 @@ def run_sim_churn(args_cli, scenario) -> None:
         "scenario": sc.name,
         "seed": sc.seed,
         "cycles": sc.cycles,
-        "pipeline_occupancy": occ_pair[0],
-        "pipeline_occupancy_pair": occ_pair,
         # pack overlap (PR 15): the pair IS the overlap A/B — A on, B
         # off. The idle fraction (gap-over-wall between device windows,
         # koord_device_idle_fraction) is the overlap's deliverable: A
@@ -1014,8 +1008,8 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
     pipeline must be byte-for-byte the serial path.
 
     Returns the JSON fields: steady_state_pods_per_sec, pack_seconds_warm
-    / pack_seconds_cold (the pack_incremental span), pipeline_occupancy
-    (fraction of wall where the device has work) and pipeline_parity_ok."""
+    / pack_seconds_cold (the pack_incremental span) and
+    pipeline_parity_ok."""
     from koordinator_tpu.scheduler.cycle import CyclePipeline, Scheduler
     from koordinator_tpu.scheduler.pipeline_parity import (
         _conditions,
@@ -1081,7 +1075,7 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
     log(f"steady-state cycle 0 (cold): {t_cycle0:.3f}s, pack "
         f"{pack_cold:.3f}s, {len(res0.bound)} bound")
 
-    walls, packs, busys, bound_counts = [], [], [], []
+    walls, packs, bound_counts = [], [], []
     for r in range(1, warmup + rounds + 1):
         apply_delta(store_p, r, now)
         apply_delta(store_s, r, now)
@@ -1097,7 +1091,6 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
         if r > warmup:
             walls.append(wall)
             packs.append(pack_span_seconds(sched_p))
-            busys.append(res_p.device_busy_seconds)
             bound_counts.append(len(res_p.bound))
     pipeline.flush()
     if _conditions(store_p) != _conditions(store_s):
@@ -1106,13 +1099,12 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
 
     pack_warm = float(np.median(packs))
     wall_sum = float(np.sum(walls))
-    occupancy = float(np.sum(busys)) / wall_sum if wall_sum > 0 else 0.0
     steady_pps = float(np.sum(bound_counts)) / wall_sum if wall_sum else 0.0
     speedup = pack_cold / pack_warm if pack_warm > 0 else 0.0
     log(f"steady state: {steady_pps:,.0f} pods/s end-to-end over {rounds} "
         f"rounds (median cycle {float(np.median(walls))*1000:.1f}ms); pack "
         f"warm {pack_warm*1000:.1f}ms vs cold {pack_cold*1000:.1f}ms -> "
-        f"{speedup:.1f}x; device occupancy {occupancy:.0%}; serial parity "
+        f"{speedup:.1f}x; serial parity "
         f"{'OK' if parity_ok else 'MISMATCH'}")
     cs = sched_p.snapshot_cache.stats if sched_p.snapshot_cache else {}
     if cs:
@@ -1122,7 +1114,6 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
         "pack_seconds_warm": round(pack_warm, 4),
         "pack_seconds_cold": round(pack_cold, 4),
         "pack_warm_speedup": round(speedup, 2),
-        "pipeline_occupancy": round(occupancy, 3),
         "pipeline_parity_ok": parity_ok,
         "pipeline_enabled": pipeline.enabled,
         "steady_rows_reused": int(cs.get("pod_row_hits", 0)),
@@ -1232,18 +1223,17 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
     # differs — pack/dispatch amortization across the budget.
     budget = max(sweep)
     pps_at_k = {}
-    occ_at_k = {}
     waves_seen = {}
     for k in sweep:
         store_k, _state_k = make_store()
         sched_k = Scheduler(store_k, waves=k)
         pl_k = CyclePipeline(sched_k)
         pl_k.run_cycle(now=now)  # cold build + compile
-        walls_k, bound_k, busy_k, waves_k = [], [], [], []
+        walls_k, bound_k, waves_k = [], [], []
         for r in range(1, warmup + rounds + 1):
             apply_delta(store_k, r, now)
             t = now + 2 * r
-            consumed, wall, busy, bound, deepest = 0, 0.0, 0.0, 0, 0
+            consumed, wall, bound, deepest = 0, 0.0, 0, 0
             while consumed < budget:
                 # largest power of two <= the remaining budget: an odd
                 # depth would compile a fresh fused program mid-loop in
@@ -1254,25 +1244,21 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
                 t0 = time.perf_counter()
                 res_k = pl_k.run_cycle(now=t, waves=w)
                 wall += time.perf_counter() - t0
-                busy += res_k.device_busy_seconds
                 bound += len(res_k.bound)
                 consumed += max(1, res_k.waves)
                 deepest = max(deepest, res_k.waves)
             if r > warmup:
                 walls_k.append(wall)
-                busy_k.append(busy)
                 bound_k.append(bound)
                 waves_k.append(deepest)
         pl_k.flush()
         wsum = float(np.sum(walls_k))
         pps_at_k[str(k)] = round(
             float(np.sum(bound_k)) / wsum if wsum else 0.0, 1)
-        occ_at_k[str(k)] = round(
-            float(np.sum(busy_k)) / wsum if wsum else 0.0, 3)
         waves_seen[str(k)] = int(max(waves_k)) if waves_k else 0
         log(f"wave sweep K={k}: {pps_at_k[str(k)]:,.1f} pods/s steady "
-            f"over {budget} logical cycles/round (occupancy "
-            f"{occ_at_k[str(k)]:.0%}, max logical cycles/dispatch "
+            f"over {budget} logical cycles/round (max logical "
+            f"cycles/dispatch "
             f"{waves_seen[str(k)]}, amortized fixed overhead "
             f"{fixed_ms / k:.2f}ms/round)")
     out.update({
@@ -1281,7 +1267,6 @@ def run_steady_state(args_cli, num_pods: int, num_nodes: int) -> dict:
             str(k): round(fixed_ms / k, 3) for k in sweep},
         "logical_cycles_per_round": budget,
         "pods_per_sec_at_k": pps_at_k,
-        "pipeline_occupancy_at_k": occ_at_k,
         "waves_consumed_at_k": waves_seen,
     })
     return out

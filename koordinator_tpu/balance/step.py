@@ -73,8 +73,9 @@ def build_rebalance_step(max_evict_per_node: int, jit: bool = True):
 
     cap = int(max_evict_per_node)
 
-    def step(usage_pct, has_metric, low_thr, high_thr, rhs_hi, rhs_lo,
-             pod_node, pod_prio, pod_cpu, pod_req_i, pod_ok):
+    def rebalance_step(usage_pct, has_metric, low_thr, high_thr, rhs_hi,
+                       rhs_lo, pod_node, pod_prio, pod_cpu, pod_req_i,
+                       pod_ok):
         N = usage_pct.shape[0]
         P = pod_node.shape[0]
         # ---- classification (classify_nodes, vectorized identically)
@@ -162,7 +163,7 @@ def build_rebalance_step(max_evict_per_node: int, jit: bool = True):
         return RebalanceOut(is_low, is_high, margin, cand_count,
                             sel_count, sel_pod, sel_node, sel_score)
 
-    return jax.jit(step) if jit else step
+    return jax.jit(rebalance_step) if jit else rebalance_step
 
 
 def split_rhs_limbs(usage_pct, alloc, high_thr):

@@ -255,12 +255,12 @@ def build_colo_step(cpu_policy: str, memory_policy: str,
             return by_max
         return by_usage
 
-    def step(capacity, node_reserved, system_reserved, node_used,
-             pod_all_used, hp_used, hp_request, hp_max, prod_reclaimable,
-             reclaim_pct, mid_pct, degraded,
-             q_parent, q_level, q_min, q_max, q_weight, q_guarantee,
-             q_request, q_used, q_allow_lent, q_enable_scale, q_valid,
-             q_total_base):
+    def colo_step(capacity, node_reserved, system_reserved, node_used,
+                  pod_all_used, hp_used, hp_request, hp_max, prod_reclaimable,
+                  reclaim_pct, mid_pct, degraded,
+                  q_parent, q_level, q_min, q_max, q_weight, q_guarantee,
+                  q_request, q_used, q_allow_lent, q_enable_scale, q_valid,
+                  q_total_base):
         # ---- batch/mid: slocontroller/noderesource._batch_mid_kernel,
         # the identical f32 op sequence (parity is bit-level)
         reclaimable_capacity = capacity * reclaim_pct / 100.0
@@ -314,4 +314,4 @@ def build_colo_step(cpu_policy: str, memory_policy: str,
             runtime=runtime, revoke_over=revoke_over,
             revoke_mask=revoke_mask, predicted_total=predicted_total)
 
-    return jax.jit(step) if jit else step
+    return jax.jit(colo_step) if jit else colo_step

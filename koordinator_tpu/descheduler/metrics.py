@@ -52,5 +52,6 @@ DEVICE_WINDOW_SECONDS = REGISTRY.histogram(
 )
 DEVICE_IDLE_FRACTION = REGISTRY.gauge(
     "koord_device_idle_fraction",
-    "Gap time between consecutive device windows over wall time",
+    "Host-clock gap between consecutive dispatch windows over wall time "
+    "(not device idle time)",
 )

@@ -329,77 +329,85 @@ def make_pod_evaluator(fc: FullChainInputs, weight_idx, prod_mode,
         est = inputs.estimated[i]
         is_prod_i = inputs.is_prod[i]
 
-        (gang_ok, quota_ok, fit, la_ok, cpuset_ok, numa_ok, zone, taint_ok,
-         affinity_ok, ports_ok, vol_ok) = filter_chain(
-            i, requested, numa_free, bind_free, quota_used, aff_count,
-            anti_cover, aff_exists, port_used, vol_free)
-        admit = gang_ok & quota_ok
-        feasible = (
-            inputs.node_ok & fit & la_ok & cpuset_ok & numa_ok & taint_ok
-            & affinity_ok & ports_ok & vol_ok & admit
-        )
+        with jax.named_scope("filter"):
+            (gang_ok, quota_ok, fit, la_ok, cpuset_ok, numa_ok, zone,
+             taint_ok, affinity_ok, ports_ok, vol_ok) = filter_chain(
+                i, requested, numa_free, bind_free, quota_used, aff_count,
+                anti_cover, aff_exists, port_used, vol_free)
+            admit = gang_ok & quota_ok
+            feasible = (
+                inputs.node_ok & fit & la_ok & cpuset_ok & numa_ok
+                & taint_ok & affinity_ok & ports_ok & vol_ok & admit
+            )
 
-        # ---- Score chain (equal plugin weights, each already 0..100)
-        la_score = _score_row(
-            est, is_prod_i, inputs, delta_np, delta_pr, weight_idx, prod_mode
-        )
-        numa_score = numa_score_row(
-            req, requested, inputs.allocatable, inputs.weights, weight_idx,
-        )
-        # NodeResourcesBalancedAllocation (vendored default scoring): for
-        # the two balanced axes the upstream std reduces to |fc - fm| / 2
-        # (no sqrt — the bit-parity discipline holds); fractions clamp to 1
-        # and a zero-capacity axis contributes fraction 0
-        if bal_idx[0] >= 0:
-            ci, mi = bal_idx
-            def _frac(axis, inv):
-                return jnp.minimum(
-                    (requested[:, axis] + req_fit[axis]) * inv, 1.0)
-            std = jnp.abs(_frac(ci, bal_inv_c) - _frac(mi, bal_inv_m)) * 0.5
-            bal_row = jnp.floor((1.0 - std) * 100.0)
-            numa_score = numa_score + bal_row
-        else:
-            bal_row = jnp.zeros(requested.shape[0], jnp.float32)
-        # preferred node affinity (soft NodeAffinity score): a static,
-        # profile-bucketed 0..100 row — pods without preferences add 0.
-        # Zero-column tables mean NO pod carries the feature: skip the
-        # gather entirely (snapshot emits true empties)
-        if fc.pref_scores.shape[1]:
-            pid = fc.pod_pref_id[i]
-            pref = jnp.where(
-                pid >= 0, fc.pref_scores[:, jnp.maximum(pid, 0)], 0.0)
-        else:
-            pref = jnp.zeros(aff_count.shape[0], jnp.float32)
-        # preferred POD affinity (soft InterPodAffinity score): weighted sum
-        # of matching-pod counts over the shared term space, max-min
-        # normalized to 0..100 per pod (upstream NormalizeScore semantics)
-        sid2 = fc.pod_ppref_id[i]
-        if T and fc.ppref_w.shape[0]:  # zero rows == no profiles: no work
-            w_row = fc.ppref_w[jnp.maximum(sid2, 0), :T]          # [T]
-            # elementwise+reduce, not matmul: TPU matmuls default to bf16
-            # passes and the products must stay exact integers
-            raw = jnp.sum(aff_count * w_row[None, :], axis=1)     # [N]
-            # max-min over node_ok nodes only (upstream NormalizeScore
-            # spans the candidate set — padded/cordoned rows must not
-            # anchor the scale and shift weights across bucket sizes)
-            mx = jnp.max(jnp.where(inputs.node_ok, raw, -jnp.inf))
-            mn = jnp.min(jnp.where(inputs.node_ok, raw, jnp.inf))
-            norm = jnp.where(
-                mx > mn,
-                jnp.floor((raw - mn) * 100.0 / (mx - mn)), 0.0)
-            pref = pref + jnp.where(sid2 >= 0, norm, 0.0)
-        # ImageLocality (vendored default plugin, ops/ports.py): static
-        # profile-bucketed 0..100 row, like preferred node affinity
-        if fc.img_scores.shape[1]:
-            iid = fc.pod_img_id[i]
-            pref = pref + jnp.where(
-                iid >= 0, fc.img_scores[:, jnp.maximum(iid, 0)], 0.0)
-        score = la_score + numa_score + pref
-        score = jnp.where(feasible, score, -1.0)
+        with jax.named_scope("score"):
+            # ---- Score chain (equal plugin weights, each already 0..100)
+            la_score = _score_row(
+                est, is_prod_i, inputs, delta_np, delta_pr, weight_idx,
+                prod_mode
+            )
+            numa_score = numa_score_row(
+                req, requested, inputs.allocatable, inputs.weights,
+                weight_idx,
+            )
+            # NodeResourcesBalancedAllocation (vendored default scoring):
+            # for the two balanced axes the upstream std reduces to
+            # |fc - fm| / 2 (no sqrt — the bit-parity discipline holds);
+            # fractions clamp to 1 and a zero-capacity axis contributes
+            # fraction 0
+            if bal_idx[0] >= 0:
+                ci, mi = bal_idx
+                def _frac(axis, inv):
+                    return jnp.minimum(
+                        (requested[:, axis] + req_fit[axis]) * inv, 1.0)
+                std = jnp.abs(
+                    _frac(ci, bal_inv_c) - _frac(mi, bal_inv_m)) * 0.5
+                bal_row = jnp.floor((1.0 - std) * 100.0)
+                numa_score = numa_score + bal_row
+            else:
+                bal_row = jnp.zeros(requested.shape[0], jnp.float32)
+            # preferred node affinity (soft NodeAffinity score): a static,
+            # profile-bucketed 0..100 row — pods without preferences add 0.
+            # Zero-column tables mean NO pod carries the feature: skip the
+            # gather entirely (snapshot emits true empties)
+            if fc.pref_scores.shape[1]:
+                pid = fc.pod_pref_id[i]
+                pref = jnp.where(
+                    pid >= 0, fc.pref_scores[:, jnp.maximum(pid, 0)], 0.0)
+            else:
+                pref = jnp.zeros(aff_count.shape[0], jnp.float32)
+            # preferred POD affinity (soft InterPodAffinity score):
+            # weighted sum of matching-pod counts over the shared term
+            # space, max-min normalized to 0..100 per pod (upstream
+            # NormalizeScore semantics)
+            sid2 = fc.pod_ppref_id[i]
+            if T and fc.ppref_w.shape[0]:  # zero rows == no profiles: no work
+                w_row = fc.ppref_w[jnp.maximum(sid2, 0), :T]          # [T]
+                # elementwise+reduce, not matmul: TPU matmuls default to bf16
+                # passes and the products must stay exact integers
+                raw = jnp.sum(aff_count * w_row[None, :], axis=1)     # [N]
+                # max-min over node_ok nodes only (upstream NormalizeScore
+                # spans the candidate set — padded/cordoned rows must not
+                # anchor the scale and shift weights across bucket sizes)
+                mx = jnp.max(jnp.where(inputs.node_ok, raw, -jnp.inf))
+                mn = jnp.min(jnp.where(inputs.node_ok, raw, jnp.inf))
+                norm = jnp.where(
+                    mx > mn,
+                    jnp.floor((raw - mn) * 100.0 / (mx - mn)), 0.0)
+                pref = pref + jnp.where(sid2 >= 0, norm, 0.0)
+            # ImageLocality (vendored default plugin, ops/ports.py): static
+            # profile-bucketed 0..100 row, like preferred node affinity
+            if fc.img_scores.shape[1]:
+                iid = fc.pod_img_id[i]
+                pref = pref + jnp.where(
+                    iid >= 0, fc.img_scores[:, jnp.maximum(iid, 0)], 0.0)
+            score = la_score + numa_score + pref
+            score = jnp.where(feasible, score, -1.0)
 
         # ---- select
-        best = jnp.argmax(score)
-        found = (score[best] >= 0.0) & inputs.pod_valid[i]
+        with jax.named_scope("select"):
+            best = jnp.argmax(score)
+            found = (score[best] >= 0.0) & inputs.pod_valid[i]
         # score/bal rows + best value ride along for the wave kernel's
         # balanced-allocation conflict bound; the serial loop ignores them
         # (XLA dead-code-eliminates the unused outputs)
@@ -453,66 +461,68 @@ def commit_pod_state(fc: FullChainInputs, prod_mode: bool, state, i, found,
     IDENTICAL update sequence — both its in-wave pass and its kept-only
     replay pass call this function, so carried state can never drift from
     what the serial kernel would have produced."""
-    inputs = fc.base
-    (requested, delta_np, delta_pr, numa_free, bind_free, quota_used,
-     aff_count, anti_cover, aff_exists, port_used, vol_free) = state
-    T = fc.aff_dom.shape[1]
-    PT = fc.port_used.shape[1]
-    req_fit = inputs.fit_requests[i]
-    req = fc.requests[i]
-    est = inputs.estimated[i]
-    is_prod_i = inputs.is_prod[i]
-    fnd = found.astype(jnp.float32)
+    with jax.named_scope("commit"):
+        inputs = fc.base
+        (requested, delta_np, delta_pr, numa_free, bind_free, quota_used,
+         aff_count, anti_cover, aff_exists, port_used, vol_free) = state
+        T = fc.aff_dom.shape[1]
+        PT = fc.port_used.shape[1]
+        req_fit = inputs.fit_requests[i]
+        req = fc.requests[i]
+        est = inputs.estimated[i]
+        is_prod_i = inputs.is_prod[i]
+        fnd = found.astype(jnp.float32)
 
-    def upd_row(mat, add_row):
-        new_row = mat[best] + fnd * add_row
-        return jax.lax.dynamic_update_slice(mat, new_row[None], (best, 0))
+        def upd_row(mat, add_row):
+            new_row = mat[best] + fnd * add_row
+            return jax.lax.dynamic_update_slice(mat, new_row[None], (best, 0))
 
-    requested = upd_row(requested, req_fit)
-    delta_np = upd_row(delta_np, est)
-    if prod_mode:
-        delta_pr = upd_row(
-            delta_pr, jnp.where(is_prod_i, 1.0, 0.0) * est
+        requested = upd_row(requested, req_fit)
+        delta_np = upd_row(delta_np, est)
+        if prod_mode:
+            delta_pr = upd_row(
+                delta_pr, jnp.where(is_prod_i, 1.0, 0.0) * est
+            )
+        new_zone_free = numa_spread_fill(numa_free[best], req, zone_at_best)
+        apply_numa = (found & fc.needs_numa[i]).astype(jnp.float32)
+        mixed = (apply_numa * new_zone_free
+                 + (1.0 - apply_numa) * numa_free[best])
+        numa_free = jax.lax.dynamic_update_slice(
+            numa_free, mixed[None], (best, 0, 0)
         )
-    new_zone_free = numa_spread_fill(numa_free[best], req, zone_at_best)
-    apply_numa = (found & fc.needs_numa[i]).astype(jnp.float32)
-    mixed = apply_numa * new_zone_free + (1.0 - apply_numa) * numa_free[best]
-    numa_free = jax.lax.dynamic_update_slice(
-        numa_free, mixed[None], (best, 0, 0)
-    )
-    bind_free = bind_free.at[best].add(
-        -fnd * jnp.where(fc.needs_bind[i], fc.cores_needed[i], 0.0)
-    )
-    # NodePorts: the placed pod binds its wanted slots on the node
-    if PT:
-        port_row = jnp.maximum(
-            port_used[best],
-            fnd * fc.pod_port_wants[i].astype(jnp.float32))
-        port_used = jax.lax.dynamic_update_slice(
-            port_used, port_row[None], (best, 0))
-    vol_free = vol_free.at[best].add(
-        -fnd * fc.vol_needed[i][fc.node_vol_group[best]])
-    quota_used = quota_used_add_row(
-        quota_used, req, fc.quota_id[i], fc.quota_ancestors, found
-    )
-    # inter-pod affinity: the placed pod raises the count of every
-    # term it matches across the chosen node's whole domain, flips
-    # the term's exists flag even on an unlabeled node, and — for
-    # terms it CARRIES as anti-affinity — raises the domain's
-    # anti_cover (symmetric anti-affinity for later pods)
-    for t in range(T):
-        chosen_dom = fc.aff_dom[best, t]
-        in_dom = (chosen_dom >= 0) & (fc.aff_dom[:, t] == chosen_dom)
-        inc = found & fc.pod_aff_match[i, t] & in_dom
-        aff_count = aff_count.at[:, t].add(inc.astype(jnp.float32))
-        inc_cov = found & fc.pod_anti_req[i, t] & in_dom
-        anti_cover = anti_cover.at[:, t].add(
-            inc_cov.astype(jnp.float32))
-        aff_exists = aff_exists.at[t].set(
-            aff_exists[t] | (found & fc.pod_aff_match[i, t]))
-    return (requested, delta_np, delta_pr, numa_free, bind_free,
-            quota_used, aff_count, anti_cover, aff_exists, port_used,
-            vol_free)
+        bind_free = bind_free.at[best].add(
+            -fnd * jnp.where(fc.needs_bind[i], fc.cores_needed[i], 0.0)
+        )
+        # NodePorts: the placed pod binds its wanted slots on the node
+        if PT:
+            port_row = jnp.maximum(
+                port_used[best],
+                fnd * fc.pod_port_wants[i].astype(jnp.float32))
+            port_used = jax.lax.dynamic_update_slice(
+                port_used, port_row[None], (best, 0))
+        vol_free = vol_free.at[best].add(
+            -fnd * fc.vol_needed[i][fc.node_vol_group[best]])
+        quota_used = quota_used_add_row(
+            quota_used, req, fc.quota_id[i], fc.quota_ancestors, found
+        )
+        # inter-pod affinity: the placed pod raises the count of every
+        # term it matches across the chosen node's whole domain, flips
+        # the term's exists flag even on an unlabeled node, and — for
+        # terms it CARRIES as anti-affinity — raises the domain's
+        # anti_cover (symmetric anti-affinity for later pods)
+        for t in range(T):
+            chosen_dom = fc.aff_dom[best, t]
+            in_dom = (chosen_dom >= 0) & (fc.aff_dom[:, t] == chosen_dom)
+            inc = found & fc.pod_aff_match[i, t] & in_dom
+            aff_count = aff_count.at[:, t].add(inc.astype(jnp.float32))
+            inc_cov = found & fc.pod_anti_req[i, t] & in_dom
+            anti_cover = anti_cover.at[:, t].add(
+                inc_cov.astype(jnp.float32))
+            aff_exists = aff_exists.at[t].set(
+                aff_exists[t] | (found & fc.pod_aff_match[i, t]))
+        return (requested, delta_np, delta_pr, numa_free, bind_free,
+                quota_used, aff_count, anti_cover, aff_exists, port_used,
+                vol_free)
 
 
 def build_full_chain_step(args: LoadAwareArgs, num_gangs: int, num_groups: int,
@@ -595,11 +605,12 @@ def build_full_chain_step(args: LoadAwareArgs, num_gangs: int, num_groups: int,
         terms = out[-2] if explain_full else None
 
         # ---- Permit barrier (gang group all-or-nothing)
-        keep = gang_permit_mask(
-            chosen, fc.gang_id, fc.gang_min_member, fc.gang_assumed,
-            fc.gang_group_id, num_gangs, num_groups,
-        )
-        chosen = jnp.where(keep, chosen, -1)
+        with jax.named_scope("select"):
+            keep = gang_permit_mask(
+                chosen, fc.gang_id, fc.gang_min_member, fc.gang_assumed,
+                fc.gang_group_id, num_gangs, num_groups,
+            )
+            chosen = jnp.where(keep, chosen, -1)
         if explain is None:
             return chosen, requested, quota_used
         # attribution counts at CYCLE-START state — diagnose.py's contract
@@ -611,13 +622,15 @@ def build_full_chain_step(args: LoadAwareArgs, num_gangs: int, num_groups: int,
         return chosen, requested, quota_used, ExplainOut(counts, terms)
 
     if explain is None:
-        def step(fc: FullChainInputs):
+        def full_chain_xla(fc: FullChainInputs):
             return _step_impl(fc, None)
-    else:
-        def step(fc: FullChainInputs, n_real):
-            return _step_impl(fc, n_real)
 
-    return jax.jit(step) if jit else step
+        return jax.jit(full_chain_xla) if jit else full_chain_xla
+
+    def full_chain_xla_explain(fc: FullChainInputs, n_real):
+        return _step_impl(fc, n_real)
+
+    return jax.jit(full_chain_xla_explain) if jit else full_chain_xla_explain
 
 
 def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,

@@ -501,12 +501,13 @@ def _make_wave_body(fc: FullChainInputs, sides: WaveSideInputs, n_real,
         wave_terms = pass1[-2] if explain_full else None
 
         # ---- Permit barrier against the CARRIED assumed counters
-        keep = gang_permit_mask(
-            chosen, fc.gang_id, fc.gang_min_member, gang_assumed,
-            fc.gang_group_id, num_gangs, num_groups,
-        )
-        kept = (chosen >= 0) & keep
-        kept_count = jnp.sum(kept.astype(jnp.int32))
+        with jax.named_scope("select"):
+            keep = gang_permit_mask(
+                chosen, fc.gang_id, fc.gang_min_member, gang_assumed,
+                fc.gang_group_id, num_gangs, num_groups,
+            )
+            kept = (chosen >= 0) & keep
+            kept_count = jnp.sum(kept.astype(jnp.int32))
         if explain_full:
             # the wave that finally KEEPS a pod owns its attribution
             # row (a Permit-reverted choice never persisted host-side)
@@ -746,13 +747,16 @@ def build_fused_wave_step(args: LoadAwareArgs, num_gangs: int,
                               out[_EX_TERMS] if explain_full else None)
 
     if explain is None:
-        def step(fc: FullChainInputs, sides: WaveSideInputs):
+        def fused_waves(fc: FullChainInputs, sides: WaveSideInputs):
             return _step_impl(fc, sides, None)
-    else:
-        def step(fc: FullChainInputs, sides: WaveSideInputs, n_real):
-            return _step_impl(fc, sides, n_real)
 
-    return jax.jit(step) if jit else step
+        return jax.jit(fused_waves) if jit else fused_waves
+
+    def fused_waves_explain(fc: FullChainInputs, sides: WaveSideInputs,
+                            n_real):
+        return _step_impl(fc, sides, n_real)
+
+    return jax.jit(fused_waves_explain) if jit else fused_waves_explain
 
 
 def build_chained_wave_step(args: LoadAwareArgs, num_gangs: int,
@@ -821,11 +825,13 @@ def build_chained_wave_step(args: LoadAwareArgs, num_gangs: int,
         return new_carry, rows, out[_EX_COUNTS][0]
 
     if explain is None:
-        def step(fc: FullChainInputs, carry, sides: WaveSideInputs):
+        def chained_wave(fc: FullChainInputs, carry, sides: WaveSideInputs):
             return _step_impl(fc, carry, sides, None)
-    else:
-        def step(fc: FullChainInputs, carry, sides: WaveSideInputs,
-                 n_real):
-            return _step_impl(fc, carry, sides, n_real)
 
-    return jax.jit(step) if jit else step
+        return jax.jit(chained_wave) if jit else chained_wave
+
+    def chained_wave_explain(fc: FullChainInputs, carry,
+                             sides: WaveSideInputs, n_real):
+        return _step_impl(fc, carry, sides, n_real)
+
+    return jax.jit(chained_wave_explain) if jit else chained_wave_explain

@@ -132,7 +132,7 @@ def build_schedule_step(args: LoadAwareArgs, jit: bool = True):
     weight_idx = tuple(int(i) for i in np.nonzero(args.weight_vector())[0])
     prod_mode = args.score_according_prod_usage
 
-    def step(inputs: ScheduleInputs):
+    def schedule_xla(inputs: ScheduleInputs):
         P = inputs.fit_requests.shape[0]
         N = inputs.allocatable.shape[0]
         reject_np, reject_prod = la_ops.loadaware_node_reject(
@@ -179,7 +179,7 @@ def build_schedule_step(args: LoadAwareArgs, jit: bool = True):
         requested, _, _, chosen = jax.lax.fori_loop(0, P, body, init)
         return chosen, requested
 
-    return jax.jit(step) if jit else step
+    return jax.jit(schedule_xla) if jit else schedule_xla
 
 
 def build_best_schedule_step(args: LoadAwareArgs, vmem_budget_bytes=None):
@@ -218,7 +218,7 @@ def build_score_matrix(args: LoadAwareArgs, jit: bool = True):
     prod_mode = args.score_according_prod_usage
     weight_idx = tuple(int(i) for i in np.nonzero(args.weight_vector())[0])
 
-    def fn(inputs: ScheduleInputs):
+    def score_matrix(inputs: ScheduleInputs):
         reject_np, reject_prod = la_ops.loadaware_node_reject(
             inputs.allocatable,
             inputs.la_filter_usage,
@@ -251,4 +251,4 @@ def build_score_matrix(args: LoadAwareArgs, jit: bool = True):
         )
         return feasible, score
 
-    return jax.jit(fn) if jit else fn
+    return jax.jit(score_matrix) if jit else score_matrix

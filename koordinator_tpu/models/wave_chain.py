@@ -64,7 +64,7 @@ def build_wave_full_chain_step(args: LoadAwareArgs, num_gangs: int,
     bal_idx = resolve_balance_idx(active_axes)
     prod_mode = args.score_according_prod_usage
 
-    def step(fc: FullChainInputs):
+    def full_chain_wave(fc: FullChainInputs):
         inputs = fc.base
         P, R = inputs.fit_requests.shape
         N = inputs.allocatable.shape[0]
@@ -332,4 +332,4 @@ def build_wave_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         chosen = jnp.where(keep, chosen, -1)
         return chosen, requested, quota_used
 
-    return jax.jit(step) if jit else step
+    return jax.jit(full_chain_wave) if jit else full_chain_wave

@@ -19,12 +19,18 @@ execute, host-side bind work) first-class.
 Thread discipline: the span stack is thread-local (each thread traces its
 own tree); the finished-root ring is shared and lock-guarded. koordlint's
 concurrency rules gate this package — no unlocked shared mutation.
+
+Profiler clock: a span at depth 0 or 1 (the cycle skeleton) also opens a
+``jax.profiler.TraceAnnotation`` of the same name when JAX is loaded in
+the process, so a ``jax.profiler`` trace shows the skeleton on its host
+plane beside the device ops. This package never imports JAX itself.
 """
 
 from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -159,6 +165,18 @@ def load_jsonl_bundle(lines, *, validate_header, validate_record,
     return header, records, errors
 
 
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for ``name``, or None
+    when the process has not loaded JAX (no profiler can be running) or
+    is still importing it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Tracer:
     """Nested-span tracer with a bounded finished-root ring.
 
@@ -171,7 +189,7 @@ class Tracer:
     Memory is bounded on BOTH axes (audit.py discipline): the ring keeps
     at most `capacity` roots, and each trace retains at most
     `max_spans_per_trace` spans. Only spans at depth >= 2 (per-item work:
-    `bind_pod` and below on a 10k-pod cycle) count against the budget —
+    `wave_replay` and below on a chained cycle) count against the budget —
     the root and its direct children are the per-stage skeleton, bounded
     by instrumentation sites rather than cluster size, and must survive
     even when a huge pre-pass burns the budget first. Spans beyond the
@@ -208,6 +226,10 @@ class Tracer:
         elif len(stack) >= 2:
             self._local.retained += 1  # skeleton spans don't consume budget
         span_id = next(self._ids)
+        # the skeleton also rides the profiler's clock; per-item depths
+        # do not (their count grows with the work, and nothing reads
+        # them there)
+        ann = _profiler_annotation(name) if len(stack) < 2 else None
         sp = Span(
             name=name,
             trace_id=parent.trace_id if parent is not None else span_id,
@@ -225,6 +247,8 @@ class Tracer:
             raise
         finally:
             sp.duration_seconds = time.perf_counter() - sp.start_mono
+            if ann is not None:
+                ann.__exit__(None, None, None)
             stack.pop()
             if parent is not None:
                 if not over_budget:

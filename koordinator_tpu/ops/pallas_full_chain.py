@@ -506,7 +506,9 @@ def build_pallas_full_chain_step(args: LoadAwareArgs, num_gangs: int,
     weights = np.asarray(full_weights, np.float32)
     prod_mode = args.score_according_prod_usage
 
-    def step(fc: FullChainInputs) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    def full_chain_pallas(
+            fc: FullChainInputs
+    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         inputs = fc.base
         P, R = inputs.fit_requests.shape
         N = inputs.allocatable.shape[0]
@@ -728,4 +730,4 @@ def build_pallas_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         quota_used = qused_t[:, :G].T if G else fc.quota_used
         return chosen, requested_t.T, quota_used
 
-    return jax.jit(step) if jit else step
+    return jax.jit(full_chain_pallas) if jit else full_chain_pallas

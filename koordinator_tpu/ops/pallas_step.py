@@ -158,7 +158,7 @@ def build_pallas_schedule_step(args: LoadAwareArgs, interpret: bool = False,
     prod_mode = args.score_according_prod_usage
     weights = np.asarray(args.weight_vector(), np.float32)
 
-    def step(inputs) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def schedule_pallas(inputs) -> Tuple[jnp.ndarray, jnp.ndarray]:
         P, R = inputs.fit_requests.shape
         N = inputs.allocatable.shape[0]
         reject_np, reject_prod = la_ops.loadaware_node_reject(
@@ -218,4 +218,4 @@ def build_pallas_schedule_step(args: LoadAwareArgs, interpret: bool = False,
         )(*grid_inputs)
         return chosen[:P, 0], requested_t.T
 
-    return jax.jit(step) if jit else step
+    return jax.jit(schedule_pallas) if jit else schedule_pallas

@@ -19,6 +19,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from koordinator_tpu.colo.step import build_colo_step
+from koordinator_tpu.parallel.mesh import mesh_program
 
 
 def build_sharded_colo_step(cpu_policy: str, memory_policy: str,
@@ -31,4 +32,4 @@ def build_sharded_colo_step(cpu_policy: str, memory_policy: str,
     quota fold."""
     raw = build_colo_step(cpu_policy, memory_policy, jit=False)
     rep = NamedSharding(mesh, P())
-    return jax.jit(raw, out_shardings=rep)
+    return jax.jit(mesh_program(raw), out_shardings=rep)

@@ -38,6 +38,7 @@ from koordinator_tpu.models.full_chain import (
 from koordinator_tpu.ops.loadaware import LoadAwareArgs
 from koordinator_tpu.parallel.mesh import (
     _node_axis_spec,
+    mesh_program,
     put_on_mesh,
     shard_inputs_nodewise,
 )
@@ -112,7 +113,7 @@ def build_sharded_full_chain_step(
         # its sharding slot must be None too or the structures mismatch.
         out_shardings = out_shardings + (
             ExplainOut(rep, rep if explain == "full" else None),)
-    return jax.jit(raw, out_shardings=out_shardings)
+    return jax.jit(mesh_program(raw), out_shardings=out_shardings)
 
 
 def build_sharded_fused_wave_step(
@@ -160,7 +161,7 @@ def build_sharded_fused_wave_step(
     else:
         out_shardings = (
             fw_out, ExplainOut(rep, rep if explain == "full" else None))
-    return jax.jit(raw, out_shardings=out_shardings)
+    return jax.jit(mesh_program(raw), out_shardings=out_shardings)
 
 
 def wave_carry_shardings(mesh: Mesh, explain=None, prod: bool = False,
@@ -263,4 +264,4 @@ def build_sharded_chained_wave_step(
     )
     if explain is not None:
         out_shardings = out_shardings + (rep,)  # this wave's counts row
-    return jax.jit(raw, out_shardings=out_shardings)
+    return jax.jit(mesh_program(raw), out_shardings=out_shardings)

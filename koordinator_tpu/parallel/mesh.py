@@ -106,6 +106,17 @@ def surviving_submesh(mesh: Mesh, lost_device_ids) -> Optional[Mesh]:
     return make_mesh(survivors)
 
 
+def mesh_program(raw):
+    """``raw`` (a builder's unjitted step) renamed ``<its name>_mesh``, so
+    the sharded program is told apart from the single-device one in a
+    device trace's ``XLA Modules``."""
+    def program(*operands):
+        return raw(*operands)
+
+    program.__name__ = program.__qualname__ = raw.__name__ + "_mesh"
+    return program
+
+
 def _node_axis_spec(mesh: Mesh, flat: bool) -> P:
     # serial mode shards nodes over every device (both mesh axes)
     return P(("pods", "nodes")) if flat else P("nodes")
@@ -245,11 +256,11 @@ def build_sharded_schedule_step(args: LoadAwareArgs, mesh: Mesh):
         NamedSharding(mesh, P()),          # chosen [P] replicated
         NamedSharding(mesh, node_spec),    # requested [N, R]
     )
-    return jax.jit(raw, out_shardings=out_shardings)
+    return jax.jit(mesh_program(raw), out_shardings=out_shardings)
 
 
 def build_sharded_score_matrix(args: LoadAwareArgs, mesh: Mesh):
     """One-shot [P, N] matrix jitted over the 2-D mesh."""
     raw = build_score_matrix(args, jit=False)
     out = NamedSharding(mesh, P("pods", "nodes"))
-    return jax.jit(raw, out_shardings=(out, out))
+    return jax.jit(mesh_program(raw), out_shardings=(out, out))

@@ -19,6 +19,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from koordinator_tpu.balance.step import build_rebalance_step
+from koordinator_tpu.parallel.mesh import mesh_program
 
 
 def build_sharded_rebalance_step(max_evict_per_node: int, mesh: Mesh):
@@ -29,4 +30,4 @@ def build_sharded_rebalance_step(max_evict_per_node: int, mesh: Mesh):
     the candidate-sort collectives."""
     raw = build_rebalance_step(max_evict_per_node, jit=False)
     rep = NamedSharding(mesh, P())
-    return jax.jit(raw, out_shardings=rep)
+    return jax.jit(mesh_program(raw), out_shardings=rep)

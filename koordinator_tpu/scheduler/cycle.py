@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import logging
 import time
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -298,6 +299,26 @@ class _DeferredFlushTxn:
         if self.pending:
             self._store.update_many(KIND_POD, list(self.pending.values()))
             self.pending.clear()
+
+
+class _BindTally:
+    """Reserve and PreBind time and Reserve vetoes (by plugin) summed over
+    the binds under one enclosing span; written onto it as the
+    ``reserve_ms``, ``prebind_ms`` and ``vetoes`` attributes."""
+
+    __slots__ = ("reserve_s", "prebind_s", "vetoes")
+
+    def __init__(self) -> None:
+        self.reserve_s = 0.0
+        self.prebind_s = 0.0
+        self.vetoes: Dict[str, int] = {}
+
+    def write(self, span) -> None:
+        span.attributes["reserve_ms"] = f"{self.reserve_s * 1000.0:.3f}"
+        span.attributes["prebind_ms"] = f"{self.prebind_s * 1000.0:.3f}"
+        if self.vetoes:
+            span.attributes["vetoes"] = ",".join(
+                f"{name}={n}" for name, n in sorted(self.vetoes.items()))
 
 
 class _WaveStateMirror:
@@ -785,6 +806,8 @@ class Scheduler:
         # dispatch drains them in ONE flush instead of K store-write
         # batches serializing against the next dispatch
         self._defer_condition_writes = False
+        # the innermost open bind span's tally (_bind_span)
+        self._bind_tally: Optional[_BindTally] = None
         # last DeviceSnapshot stats snapshot, for counter deltas
         self._upload_stats_last: Dict[str, int] = {}
         # admission grouping of the last encode: raw arrays, with the
@@ -1623,7 +1646,8 @@ class Scheduler:
                 if (self.pipeline_mode and carried_deferred
                         and not self._flushed_this_cycle
                         and self._deferred_diagnose):
-                    self.flush_deferred()
+                    with self.tracer.span("status", deferred="1"):
+                        self.flush_deferred()
         except Exception as exc:
             # flight-recorder trigger: an unhandled cycle exception leaves
             # the wreck behind — the partial result, the span tree (the
@@ -1643,13 +1667,6 @@ class Scheduler:
         result.demotions = list(self._cycle_demotions)
         result.decision_ids = list(self._cycle_decision_ids)
         scheduler_metrics.CYCLE_SECONDS.observe(result.duration_seconds)
-        if result.duration_seconds > 0:
-            # device-busy fraction of this cycle: the "is the device the
-            # bottleneck yet" gauge (bench's pipeline_occupancy, now on
-            # /metrics). Clamped — the busy window is wall-clock around
-            # dispatch..last-readback and timer skew must not read >1.
-            scheduler_metrics.PIPELINE_OCCUPANCY.set(min(
-                1.0, result.device_busy_seconds / result.duration_seconds))
         if result.bound:
             scheduler_metrics.PODS_BOUND_TOTAL.inc(len(result.bound))
         self.extender.monitor.record(result)
@@ -1800,36 +1817,41 @@ class Scheduler:
         # RunReservePluginsReserve + RunResizePod analog)
         from koordinator_tpu.utils.features import SCHEDULER_GATES
 
-        if SCHEDULER_GATES.enabled("ResizePod"):
-            self._process_resizes(now, result)
         res_plugin = self.extender.plugin("Reservation")
-        if self.reservation_controller is not None:
-            self.reservation_controller.reconcile(now)
-        if self.quota_revoke_controller is not None:
-            self.quota_revoke_controller.reconcile(now)
-        pending, pending_reservations = self._pending_queue(now)
-        # permit-timeout rejection: pods of terminally-failed gangs never
-        # re-enter admission (gang.go WaitingPods timeout semantics)
         gang_plugin = self.extender.plugin("Coscheduling")
-        if gang_plugin is not None:
-            gang_plugin.update_pod_group_status(self.store, now)
-            dead_gangs = set(gang_plugin.timed_out_gangs())
-            if dead_gangs:
-                kept = []
-                timed_out: List[Tuple[Pod, str]] = []
-                for pod in pending:
-                    if pod.gang_key in dead_gangs:
-                        result.rejected.append(pod.meta.key)
-                        self.extender.error_handlers.dispatch(
-                            pod, "gang schedule timeout")
-                        timed_out.append((pod, "gang schedule timeout"))
-                    else:
-                        kept.append(pod)
-                pending = kept
-                # these pods never reach the batch pass, so the terminal
-                # reason must land on their status here (the end-of-cycle
-                # writer only sees batch-pass failures)
-                self._write_unschedulable_conditions([], timed_out, now)
+        with self.tracer.span("queue") as qsp:
+            if SCHEDULER_GATES.enabled("ResizePod"):
+                self._process_resizes(now, result)
+            if self.reservation_controller is not None:
+                self.reservation_controller.reconcile(now)
+            if self.quota_revoke_controller is not None:
+                self.quota_revoke_controller.reconcile(now)
+            pending, pending_reservations = self._pending_queue(now)
+            # permit-timeout rejection: pods of terminally-failed gangs
+            # never re-enter admission (gang.go WaitingPods timeout
+            # semantics)
+            timed_out: List[Tuple[Pod, str]] = []
+            if gang_plugin is not None:
+                gang_plugin.update_pod_group_status(self.store, now)
+                dead_gangs = set(gang_plugin.timed_out_gangs())
+                if dead_gangs:
+                    kept = []
+                    for pod in pending:
+                        if pod.gang_key in dead_gangs:
+                            result.rejected.append(pod.meta.key)
+                            self.extender.error_handlers.dispatch(
+                                pod, "gang schedule timeout")
+                            timed_out.append((pod, "gang schedule timeout"))
+                        else:
+                            kept.append(pod)
+                    pending = kept
+                    # these pods never reach the batch pass, so the
+                    # terminal reason must land on their status here (the
+                    # end-of-cycle writer only sees batch-pass failures)
+                    self._write_unschedulable_conditions([], timed_out, now)
+            qsp.attributes["pods"] = str(len(pending))
+            qsp.attributes["reservations"] = str(len(pending_reservations))
+            qsp.attributes["timed_out"] = str(len(timed_out))
         if not pending:
             return
 
@@ -1839,7 +1861,8 @@ class Scheduler:
         # preemption retry, which re-transforms from scratch
         ctx = CycleContext(now=now)
         originals = {p.meta.key: p for p in pending}
-        pending = self.extender.transform_before_prefilter(pending, ctx)
+        with self.tracer.span("prefilter"):
+            pending = self.extender.transform_before_prefilter(pending, ctx)
 
         # ---- reservation nomination pre-pass. Gang/quota pods are excluded:
         # their admission barriers live in the batched kernel, and binding them
@@ -1853,7 +1876,7 @@ class Scheduler:
         # have rejected (the koordbalance drain-storm scenario caught
         # exactly that). Such pods schedule through the kernel, which
         # still counts reserved capacity via the restore transformer.
-        with self.tracer.span("reservation_prepass") as presp:
+        with self._bind_span("reservation_prepass") as presp:
             remaining: List[Pod] = []
             nominated = 0
             for pod in pending:
@@ -1888,8 +1911,10 @@ class Scheduler:
         # ---- fused multi-wave path: K dependent rounds in one device
         # dispatch, replayed host-side as logical cycles (byte-identical
         # to K sequential single-round cycles — pipeline_parity gates it)
-        k_waves = self._effective_waves(pending, pending_reservations,
-                                        waves_override)
+        with self.tracer.span("plan") as psp:
+            k_waves = self._effective_waves(pending, pending_reservations,
+                                            waves_override)
+            psp.attributes["waves"] = str(k_waves)
         if k_waves > 1:
             try:
                 # _fused_wave_cycles refreshes pod-group status at the end
@@ -1919,36 +1944,41 @@ class Scheduler:
             # already-transformed views — a non-idempotent rewrite would
             # otherwise apply twice (BeforePreFilter runs per attempt on
             # the queued pod in the reference too)
-            retry = self.extender.transform_before_prefilter(
-                [
-                    originals.get(p.meta.key, p)
-                    for p in rejected_pods + [p for p, _ in failed_pods]
-                ],
-                ctx,
-            )
+            with self.tracer.span("prefilter"):
+                retry = self.extender.transform_before_prefilter(
+                    [
+                        originals.get(p.meta.key, p)
+                        for p in rejected_pods + [p for p, _ in failed_pods]
+                    ],
+                    ctx,
+                )
             rejected_pods, failed_pods = self._batch_pass(
                 retry, now, ctx, result, pending_reservations
             )
-        for b in result.bound:
-            self._preempt_attempted.pop(b.pod_key, None)
+        with self.tracer.span("status", failed=str(len(failed_pods)),
+                              rejected=str(len(rejected_pods))):
+            for b in result.bound:
+                self._preempt_attempted.pop(b.pod_key, None)
 
-        for pod in rejected_pods:
-            result.rejected.append(pod.meta.key)
-            self.extender.error_handlers.dispatch(pod, "admission rejected")
-        for pod, reason in failed_pods:
-            result.failed.append(pod.meta.key)
-            self.extender.error_handlers.dispatch(pod, reason)
-        # pod-status propagation (upstream PodScheduled=False/Unschedulable
-        # with the per-stage message): the reason becomes store-visible on
-        # the pod object, not just the failure trail
-        self._write_unschedulable_conditions(
-            rejected_pods, failed_pods, now)
-        # the packed batch is only needed within this cycle; don't pin
-        # tens of MB of host arrays across idle periods
-        self._last_batch = None
+            for pod in rejected_pods:
+                result.rejected.append(pod.meta.key)
+                self.extender.error_handlers.dispatch(
+                    pod, "admission rejected")
+            for pod, reason in failed_pods:
+                result.failed.append(pod.meta.key)
+                self.extender.error_handlers.dispatch(pod, reason)
+            # pod-status propagation (upstream PodScheduled=False/
+            # Unschedulable with the per-stage message): the reason
+            # becomes store-visible on the pod object, not just the
+            # failure trail
+            self._write_unschedulable_conditions(
+                rejected_pods, failed_pods, now)
+            # the packed batch is only needed within this cycle; don't
+            # pin tens of MB of host arrays across idle periods
+            self._last_batch = None
 
-        if gang_plugin is not None:
-            gang_plugin.update_pod_group_status(self.store, now)
+            if gang_plugin is not None:
+                gang_plugin.update_pod_group_status(self.store, now)
 
     # ------------------------------------------------------------------
     def _post_filter_preempt(self, rejected_pods: List[Pod],
@@ -1972,39 +2002,51 @@ class Scheduler:
         only every PREEMPT_RETRY_CYCLES (cluster state may have unblocked
         it by then — bounded drain instead of either extreme). Keys of
         pods that bound or left the queue are dropped each cycle."""
-        any_victims = False
-        if self.preemptor is not None and rejected_pods:
-            quota_rejected = [
-                p for p in rejected_pods if p.quota_name and not p.gang_name
+        with self.tracer.span("preempt") as sp:
+            victims0 = len(result.preempted_victims)
+            any_victims = False
+            quota_rejected: List[Pod] = []
+            if self.preemptor is not None and rejected_pods:
+                quota_rejected = [
+                    p for p in rejected_pods
+                    if p.quota_name and not p.gang_name
+                ]
+                for round_ in self.preemptor.post_filter(quota_rejected):
+                    any_victims = True
+                    result.preempted_victims.extend(round_.victim_keys)
+            PREEMPT_RETRY_CYCLES = 5
+            attempted: Dict[str, int] = getattr(
+                self, "_preempt_attempted", {})
+            self._preempt_attempted = attempted
+            self._cycle_seq = getattr(self, "_cycle_seq", 0) + 1
+            still_failed_keys = {p.meta.key for p, _ in failed_pods}
+            for key in [k for k in attempted if k not in still_failed_keys]:
+                del attempted[key]
+            no_fit = [
+                p for p, reason in failed_pods
+                if reason == "no feasible node" and not p.gang_name
+                and self._cycle_seq - attempted.get(p.meta.key, -10**9)
+                >= PREEMPT_RETRY_CYCLES
             ]
-            for round_ in self.preemptor.post_filter(quota_rejected):
-                any_victims = True
-                result.preempted_victims.extend(round_.victim_keys)
-        PREEMPT_RETRY_CYCLES = 5
-        attempted: Dict[str, int] = getattr(self, "_preempt_attempted", {})
-        self._preempt_attempted = attempted
-        self._cycle_seq = getattr(self, "_cycle_seq", 0) + 1
-        still_failed_keys = {p.meta.key for p, _ in failed_pods}
-        for key in [k for k in attempted if k not in still_failed_keys]:
-            del attempted[key]
-        no_fit = [
-            p for p, reason in failed_pods
-            if reason == "no feasible node" and not p.gang_name
-            and self._cycle_seq - attempted.get(p.meta.key, -10**9)
-            >= PREEMPT_RETRY_CYCLES
-        ]
-        if no_fit:
-            from koordinator_tpu.scheduler.preempt import DefaultPreemption
+            if no_fit:
+                from koordinator_tpu.scheduler.preempt import (
+                    DefaultPreemption,
+                )
 
-            preempter = DefaultPreemption(
-                self.store,
-                kernel_admission=self._resolve_admission(),
-                attempt_seed=self._cycle_seq,
-            )
-            for round_ in preempter.post_filter(no_fit):
-                any_victims = True
-                attempted[round_.preemptor_key] = self._cycle_seq
-                result.preempted_victims.extend(round_.victim_keys)
+                preempter = DefaultPreemption(
+                    self.store,
+                    kernel_admission=self._resolve_admission(),
+                    attempt_seed=self._cycle_seq,
+                )
+                for round_ in preempter.post_filter(no_fit):
+                    any_victims = True
+                    attempted[round_.preemptor_key] = self._cycle_seq
+                    result.preempted_victims.extend(round_.victim_keys)
+                sp.attributes["nodes_scanned"] = str(
+                    preempter.nodes_scanned)
+            sp.attributes["pods"] = str(len(quota_rejected) + len(no_fit))
+            sp.attributes["victims"] = str(
+                len(result.preempted_victims) - victims0)
         return any_victims
 
     # ------------------------------------------------------------------
@@ -2374,10 +2416,12 @@ class Scheduler:
             self.encode_observer(fc)
         return fc, pods, nodes, ng, ngroups, active
 
-    def _record_upload_deltas(self) -> None:
-        """DeviceSnapshot stats -> per-cycle counter deltas."""
+    def _record_upload_deltas(self, span) -> None:
+        """DeviceSnapshot stats -> per-cycle counter deltas, and the
+        ``upload`` span's bytes and field counts."""
         ds = self.device_snapshot.stats
         prev_ds = self._upload_stats_last
+        delta = {}
         for key, counter in (
             ("reused", scheduler_metrics.UPLOAD_FIELDS_REUSED),
             ("scattered", scheduler_metrics.UPLOAD_FIELDS_SCATTERED),
@@ -2385,8 +2429,13 @@ class Scheduler:
             ("bytes_scattered", scheduler_metrics.UPLOAD_BYTES_SCATTERED),
             ("bytes_put", scheduler_metrics.UPLOAD_BYTES_PUT),
         ):
-            counter.inc(ds[key] - prev_ds.get(key, 0))
+            delta[key] = ds[key] - prev_ds.get(key, 0)
+            counter.inc(delta[key])
         self._upload_stats_last = dict(ds)
+        span.attributes["bytes"] = str(
+            delta["bytes_scattered"] + delta["bytes_put"])
+        span.attributes["fields_put"] = str(delta["put"])
+        span.attributes["fields_scattered"] = str(delta["scattered"])
 
     def _readback_sync(self, n_shape: Tuple[int, int], *arrays,
                        path: str = "serial"):
@@ -2469,7 +2518,7 @@ class Scheduler:
                                            active, result)
 
         # apply bindings in queue order
-        with self.tracer.span("bind") as bsp:
+        with self._bind_span("bind") as bsp:
             bound_before = len(result.bound)
             by_key = {p.meta.key: p for p in pending}
             for i, key in enumerate(pods.keys):
@@ -2575,8 +2624,9 @@ class Scheduler:
                         # reuse the previous cycle's device buffers, small
                         # node-row deltas go up as donated scatters
                         # (snapshot_cache.DeviceSnapshot)
-                        fc = self.device_snapshot.upload(fc)
-                        self._record_upload_deltas()
+                        with self.tracer.span("upload") as usp:
+                            fc = self.device_snapshot.upload(fc)
+                            self._record_upload_deltas(usp)
                         self.device_snapshot.begin_dispatch()
                     if self._last_step_compiled:
                         # persistent warm-up index: a fresh compile's
@@ -2590,7 +2640,6 @@ class Scheduler:
                                 ng, ngroups, active, explain),
                             (fc, np.int32(len(nodes.names)))
                             if explain is not None else (fc,))
-                    t_dispatch = time.perf_counter()
                     win.mark_dispatch(self._window_path("serial"))
                     n_shape = (len(nodes.names),
                                int(np.shape(fc.base.allocatable)[0]))
@@ -2628,12 +2677,12 @@ class Scheduler:
                             # serial path: block immediately (the pre-
                             # pipeline behavior, and the
                             # KOORD_TPU_PIPELINE=0 fallback)
-                            chosen, = self._readback_sync(n_shape, chosen)
+                            with self.tracer.span("sync"):
+                                chosen, = self._readback_sync(
+                                    n_shape, chosen)
                     finally:
                         if self.device_snapshot is not None:
                             self.device_snapshot.end_dispatch()
-                    result.device_busy_seconds += (
-                        time.perf_counter() - t_dispatch)
                     # local dispatch only: a sidecar-served batch arrived
                     # over RPC — counting it as device readback would
                     # poison the readback-regression signal
@@ -2736,10 +2785,14 @@ class Scheduler:
                      pending_reservations, originals, k_waves)
         finally:
             self._defer_condition_writes = False
-            if not self.pipeline_mode and self._deferred_diagnose:
-                # ONE store-write flush for the whole dispatch (pipeline
-                # mode leaves the queue for the next kernel window)
-                self.flush_deferred()
+            with self.tracer.span("status",
+                                  failed=str(len(result.failed)),
+                                  rejected=str(len(result.rejected))):
+                if not self.pipeline_mode and self._deferred_diagnose:
+                    # ONE store-write flush for the whole dispatch
+                    # (pipeline mode leaves the queue for the next kernel
+                    # window)
+                    self.flush_deferred()
 
     def _fused_no_node_cycles(self, pending: List[Pod], now: float,
                               result: CycleResult, k_waves: int) -> None:
@@ -2752,10 +2805,12 @@ class Scheduler:
         gang_plugin = self.extender.plugin("Coscheduling")
         for _w in range(k_waves):
             self._post_filter_preempt([], failed, result)
-            for pod, reason in failed:
-                result.failed.append(pod.meta.key)
-                self.extender.error_handlers.dispatch(pod, reason)
-            self._write_unschedulable_conditions([], failed, now)
+            with self.tracer.span("status", failed=str(len(failed)),
+                                  rejected="0"):
+                for pod, reason in failed:
+                    result.failed.append(pod.meta.key)
+                    self.extender.error_handlers.dispatch(pod, reason)
+                self._write_unschedulable_conditions([], failed, now)
             result.waves += 1
             if gang_plugin is not None:
                 gang_plugin.update_pod_group_status(self.store, now)
@@ -2994,10 +3049,11 @@ class Scheduler:
                     fc = fc_host
                     up_fields = side_fields
                     if self.device_snapshot is not None:
-                        fc = self.device_snapshot.upload(fc)
-                        up_fields = self.device_snapshot.upload_fields(
-                            side_fields)
-                        self._record_upload_deltas()
+                        with self.tracer.span("upload") as usp:
+                            fc = self.device_snapshot.upload(fc)
+                            up_fields = self.device_snapshot.upload_fields(
+                                side_fields)
+                            self._record_upload_deltas(usp)
                         self.device_snapshot.begin_dispatch()
                     sides = assemble_sides(up_fields)
                     if self._last_step_compiled:
@@ -3011,7 +3067,6 @@ class Scheduler:
                                 sides_tag=list(res_ctx["tag"])),
                             (fc, sides, np.int32(len(nodes.names)))
                             if explain is not None else (fc, sides))
-                    t_dispatch = time.perf_counter()
                     win.mark_dispatch(self._window_path("fused"))
                     n_shape = (len(nodes.names),
                                int(np.shape(fc.base.allocatable)[0]))
@@ -3041,15 +3096,15 @@ class Scheduler:
                                  wave_counts) = self._readback_sync(
                                      n_shape, *compacted, path="fused")
                         else:
-                            (bind_pods, bind_nodes, bind_zones, bind_res,
-                             wave_counts) = self._readback_sync(
-                                 n_shape, *compacted, path="fused")
+                            with self.tracer.span("sync"):
+                                (bind_pods, bind_nodes, bind_zones,
+                                 bind_res,
+                                 wave_counts) = self._readback_sync(
+                                     n_shape, *compacted, path="fused")
                         waves_run = int(out.waves_run)
                     finally:
                         if self.device_snapshot is not None:
                             self.device_snapshot.end_dispatch()
-                    result.device_busy_seconds += (
-                        time.perf_counter() - t_dispatch)
                     scheduler_metrics.WAVES_PER_DISPATCH.observe(
                         float(waves_run))
                     scheduler_metrics.READBACK_BYTES.inc(
@@ -3154,7 +3209,7 @@ class Scheduler:
             if explain_counts is None and pending_succ:
                 mirror_apply(pending_succ)
             pending_succ = []
-            with self.tracer.span("bind", wave=str(w)) as bsp:
+            with self._bind_span("bind", wave=str(w)) as bsp:
                 bound_before = len(result.bound)
                 # nominated binds first (the serial pre-pass position) —
                 # a migration-created Reservation bound in an earlier
@@ -3236,32 +3291,35 @@ class Scheduler:
                 # a fresh SINGLE-round pass over the still-unbound pods
                 # (the device's later waves assumed no evictions — drop
                 # them and let the next cycle continue the budget)
-                retry = self.extender.transform_before_prefilter(
-                    [
-                        originals.get(p.meta.key, p)
-                        for p in rejected_pods
-                        + [p for p, _ in failed_pods]
-                    ],
-                    ctx,
-                )
+                with self.tracer.span("prefilter"):
+                    retry = self.extender.transform_before_prefilter(
+                        [
+                            originals.get(p.meta.key, p)
+                            for p in rejected_pods
+                            + [p for p, _ in failed_pods]
+                        ],
+                        ctx,
+                    )
                 rejected_pods, failed_pods = self._batch_pass(
                     retry, now, ctx, result, pending_reservations
                 )
                 truncate = True
-            for b in result.bound:
-                self._preempt_attempted.pop(b.pod_key, None)
-            for pod in rejected_pods:
-                result.rejected.append(pod.meta.key)
-                self.extender.error_handlers.dispatch(
-                    pod, "admission rejected")
-            for pod, reason in failed_pods:
-                result.failed.append(pod.meta.key)
-                self.extender.error_handlers.dispatch(pod, reason)
-            self._write_unschedulable_conditions(
-                rejected_pods, failed_pods, now)
+            with self.tracer.span("status", failed=str(len(failed_pods)),
+                                  rejected=str(len(rejected_pods))):
+                for b in result.bound:
+                    self._preempt_attempted.pop(b.pod_key, None)
+                for pod in rejected_pods:
+                    result.rejected.append(pod.meta.key)
+                    self.extender.error_handlers.dispatch(
+                        pod, "admission rejected")
+                for pod, reason in failed_pods:
+                    result.failed.append(pod.meta.key)
+                    self.extender.error_handlers.dispatch(pod, reason)
+                self._write_unschedulable_conditions(
+                    rejected_pods, failed_pods, now)
+                if gang_plugin is not None:
+                    gang_plugin.update_pod_group_status(self.store, now)
             result.waves += 1
-            if gang_plugin is not None:
-                gang_plugin.update_pod_group_status(self.store, now)
             if truncate:
                 break
             # advance the mirror with the device's view of this wave's
@@ -3368,8 +3426,9 @@ class Scheduler:
         # the wait would only trade a bounded block for a donation hazard
         # (deadline overruns never reach here: their abort path skips the
         # drain and rebuilds the mirror instead)
-        # koordlint: disable=naked-device-sync-without-deadline
-        jax.block_until_ready(rows.count)
+        with self.tracer.span("sync", abandoned="1"):
+            # koordlint: disable=naked-device-sync-without-deadline
+            jax.block_until_ready(rows.count)
 
     def _abort_chain_window(self, rows, window_open: bool) -> None:
         """Tear down the chain dispatch window on a host-side failure
@@ -3460,10 +3519,11 @@ class Scheduler:
                     fc = fc_host
                     up_fields = side_fields
                     if self.device_snapshot is not None:
-                        fc = self.device_snapshot.upload(fc)
-                        up_fields = self.device_snapshot.upload_fields(
-                            side_fields)
-                        self._record_upload_deltas()
+                        with self.tracer.span("upload") as usp:
+                            fc = self.device_snapshot.upload(fc)
+                            up_fields = self.device_snapshot.upload_fields(
+                                side_fields)
+                            self._record_upload_deltas(usp)
                         self.device_snapshot.begin_dispatch()
                         window_open = True
                     sides = assemble_sides(up_fields)
@@ -3542,7 +3602,6 @@ class Scheduler:
         scheduler_metrics.KERNEL_SECONDS.observe(window_seconds)
         if chain_compiled:
             self._add_compile_wall(window_seconds)
-        result.device_busy_seconds += window_seconds
         scheduler_metrics.WAVES_PER_DISPATCH.observe(float(executed))
         # the timeline window closes at the chain's LAST device sync —
         # the same dispatch->last-sync quantity the kernel span measures
@@ -3799,7 +3858,7 @@ class Scheduler:
         veto = False
         fresh = True
         txn: List[tuple] = []  # (patched, live pod, annotations, node)
-        with self.tracer.span("wave_replay", index=str(w)) as wsp:
+        with self._bind_span("wave_replay", index=str(w)) as wsp:
             bound_before = len(result.bound)
             # the previous wave's allocate-once consumption lands its
             # Succeeded transition at THIS wave's start (device pass 0a)
@@ -3951,6 +4010,18 @@ class Scheduler:
         return truncate
 
     # ------------------------------------------------------------------
+    @contextmanager
+    def _bind_span(self, name: str, **attributes: str):
+        """A span enclosing ``_reserve_and_bind`` calls; their summed
+        Reserve/PreBind time and veto tally land on it (_BindTally)."""
+        with self.tracer.span(name, **attributes) as sp:
+            outer, self._bind_tally = self._bind_tally, _BindTally()
+            try:
+                yield sp
+            finally:
+                self._bind_tally.write(sp)
+                self._bind_tally = outer
+
     def _reserve_and_bind(
         self,
         pod: Pod,
@@ -3981,29 +4052,36 @@ class Scheduler:
             )
             return None
 
-        with self.tracer.span("bind_pod", pod=pod.meta.key,
-                              node=node_name) as psp:
-            with self.tracer.span("reserve"):
-                done: List = []
-                for plugin in self.extender.plugins:
-                    err = plugin.reserve(pod, node_name, ctx)
-                    if err:
-                        for p in reversed(done):
-                            p.unreserve(pod, node_name, ctx)
-                        psp.attributes["veto"] = plugin.name
-                        return f"{plugin.name}: {err}"
-                    done.append(plugin)
-                if via_reservation is not None:
-                    res_plugin = self.extender.plugin("Reservation")
-                    res_plugin.consume(pod, via_reservation, ctx)
+        # per-pod Reserve/PreBind time and vetoes are summed onto the
+        # enclosing bind span (_bind_span), not given spans of their own
+        tally = self._bind_tally
+        t0 = time.perf_counter()
+        done: List = []
+        for plugin in self.extender.plugins:
+            err = plugin.reserve(pod, node_name, ctx)
+            if err:
+                for p in reversed(done):
+                    p.unreserve(pod, node_name, ctx)
+                if tally is not None:
+                    tally.reserve_s += time.perf_counter() - t0
+                    tally.vetoes[plugin.name] = (
+                        tally.vetoes.get(plugin.name, 0) + 1)
+                return f"{plugin.name}: {err}"
+            done.append(plugin)
+        if via_reservation is not None:
+            res_plugin = self.extender.plugin("Reservation")
+            res_plugin.consume(pod, via_reservation, ctx)
+        t1 = time.perf_counter()
 
-            with self.tracer.span("prebind"):
-                annotations: Dict[str, str] = {}
-                for plugin in self.extender.plugins:
-                    plugin.pre_bind(pod, node_name, ctx, annotations)
-                prebind = self.extender.plugin("DefaultPreBind")
-                prebind.apply_patch(pod, node_name, annotations, now=ctx.now,
-                                    txn=txn)
+        annotations: Dict[str, str] = {}
+        for plugin in self.extender.plugins:
+            plugin.pre_bind(pod, node_name, ctx, annotations)
+        prebind = self.extender.plugin("DefaultPreBind")
+        prebind.apply_patch(pod, node_name, annotations, now=ctx.now,
+                            txn=txn)
+        if tally is not None:
+            tally.reserve_s += t1 - t0
+            tally.prebind_s += time.perf_counter() - t1
         result.bound.append(BindResult(pod.meta.key, node_name, annotations))
         return None
 

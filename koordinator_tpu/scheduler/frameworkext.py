@@ -43,10 +43,6 @@ class CycleResult:
     resize_pending: List[str] = field(default_factory=list)  # resize didn't fit
     duration_seconds: float = 0.0
     kernel_seconds: float = 0.0
-    # wall spent between kernel dispatch and readback completion (the
-    # window where the device has work queued; upper bound when overlapped
-    # host work outlasts the kernel) — feeds the pipeline occupancy number
-    device_busy_seconds: float = 0.0
     skipped_not_leader: bool = False  # election-gated replica in standby
     # logical scheduling rounds this cycle consumed: 1 on the serial path,
     # up to K on a fused multi-wave dispatch (models/fused_waves.py). A

@@ -77,18 +77,6 @@ READBACK_BYTES = REGISTRY.counter(
     "Bytes read back from the device across all kernel dispatches",
 )
 
-# pipeline occupancy: fraction of the last cycle's wall time the device
-# had work in flight (device_busy_seconds / cycle duration). The whole
-# point of the batched-tensor re-expression is that the DEVICE sets the
-# cycle rate — this gauge makes the next host-side bottleneck visible in
-# /metrics instead of only in bench JSON. Overlapped wave replay
-# (KOORD_TPU_REPLAY_OVERLAP) raises it by draining the replay queue
-# while later waves execute.
-PIPELINE_OCCUPANCY = REGISTRY.gauge(
-    "koord_scheduler_pipeline_occupancy",
-    "Device-busy fraction of the last scheduling cycle's wall time",
-)
-
 # incremental-pack row traffic: steady state should be nearly all reused;
 # a repack surge means the store is churning (or a cache regression)
 PACK_ROWS_REUSED = REGISTRY.counter(
@@ -239,8 +227,9 @@ QUEUE_WAIT_SECONDS = REGISTRY.histogram(
 # koordwatch device timeline (obs/timeline.py): every device window —
 # scheduler dispatch, koordbalance rebalance pass, koordcolo pass —
 # records its dispatch->last-sync interval and the idle gap before it.
-# The idle fraction is THE number the host-tail / rebalance-overlap
-# ROADMAP items must drive down.
+# Both are host-clock intervals: the idle fraction is the share of wall
+# time between dispatch windows, not the device's own idle time (that
+# comes from a profiler trace of the device).
 DEVICE_WINDOW_SECONDS = REGISTRY.histogram(
     "koord_device_window_seconds",
     "Device-window dispatch-to-last-sync interval, labeled by consumer "
@@ -249,7 +238,8 @@ DEVICE_WINDOW_SECONDS = REGISTRY.histogram(
 )
 DEVICE_IDLE_FRACTION = REGISTRY.gauge(
     "koord_device_idle_fraction",
-    "Gap time between consecutive device windows over wall time",
+    "Host-clock gap between consecutive dispatch windows over wall time "
+    "(not device idle time)",
 )
 
 # koordwatch SLO engine (obs/slo.py): per-objective burn rate

@@ -383,6 +383,8 @@ class DefaultPreemption:
         # rotates the candidate-sampling window across retry attempts
         # (upstream's random offset analog, deterministic here)
         self.attempt_seed = attempt_seed
+        # nodes the dry-run visited, summed over post_filter's pods
+        self.nodes_scanned = 0
 
     def _static_admission(self, pod: Pod, node) -> bool:
         from koordinator_tpu.ops.taints import (
@@ -699,6 +701,7 @@ class DefaultPreemption:
                 )
                 if best is None or score < best[0]:
                     best = (score, node, victims)
+            self.nodes_scanned += visited
             if best is None:
                 continue
             _, node, victims = best

@@ -712,6 +712,12 @@ def _pad_bucket(n: int) -> int:
     return p
 
 
+def snapshot_row_scatter(a, idx, rows):
+    """The DeviceSnapshot's row-delta program: ``a`` with rows ``idx``
+    replaced by ``rows`` (its own name in the device trace)."""
+    return a.at[idx].set(rows)
+
+
 def _mesh_node_fields() -> Set[str]:
     """Field names (FullChainInputs + ScheduleInputs + the fused side
     arrays) whose leading axis is the node axis — the set the mesh-backed
@@ -836,7 +842,7 @@ class DeviceSnapshot:
             # node sharding: the dirty rows (replicated operands) land on
             # their owning shard via XLA's shard-local scatter lowering —
             # no reshard, no cross-shard traffic beyond the tiny operands
-            fn = jax.jit(lambda a, i, r: a.at[i].set(r),
+            fn = jax.jit(snapshot_row_scatter,
                          donate_argnums=(0,) if donate else (),
                          out_shardings=sharding)
             self._scatter_cache[key] = fn

@@ -170,12 +170,10 @@ class SimReport:
         default_factory=list)
     binding_log: List[str] = dataclasses.field(default_factory=list)
     wall_seconds: float = 0.0
-    # pipeline-occupancy accounting under realistic arrivals: per-cycle
-    # wall and device-busy sums, plus bound/wall bucketed by the logical
-    # cycles each dispatch consumed (CycleResult.waves) — the churn-side
-    # pods_per_sec_at_k / pipeline_occupancy the bench report cites
+    # per-cycle wall sum, plus bound/wall bucketed by the logical cycles
+    # each dispatch consumed (CycleResult.waves) — the churn-side
+    # pods_per_sec_at_k the bench report cites
     cycle_wall_seconds: float = 0.0
-    device_busy_seconds: float = 0.0
     wall_by_waves: Dict[int, float] = dataclasses.field(default_factory=dict)
     bound_by_waves: Dict[int, int] = dataclasses.field(default_factory=dict)
 
@@ -398,17 +396,12 @@ class SimReport:
             "pipeline": {
                 "device_idle_fraction": round(
                     self.device_idle_fraction, 3),
-                "occupancy": (
-                    round(self.device_busy_seconds
-                          / self.cycle_wall_seconds, 3)
-                    if self.cycle_wall_seconds > 0 else 0.0),
                 "pods_per_sec_at_k": {
                     str(k): round(self.bound_by_waves.get(k, 0)
                                   / self.wall_by_waves[k], 1)
                     for k in sorted(self.wall_by_waves)
                     if self.wall_by_waves[k] > 0},
                 "cycle_wall_seconds": round(self.cycle_wall_seconds, 2),
-                "device_busy_seconds": round(self.device_busy_seconds, 2),
             },
         }
         if include_log:
@@ -1305,7 +1298,6 @@ class ChurnSimulator:
             return
         wall = time.perf_counter() - t_cycle
         self.report.cycle_wall_seconds += wall
-        self.report.device_busy_seconds += result.device_busy_seconds
         # koordwatch demotion profile: a cycle that ran below its
         # configured level carries its structured reasons; attribute the
         # cycle to the FIRST (the chokepoint appends in hit order), so

@@ -166,7 +166,7 @@ _register(Scenario(
     queue_cap=384, overflow_cap=1536,
     ttb_slo_seconds=300.0,
     waves="auto",
-    # the citable occupancy/throughput pair runs through the production
+    # the citable throughput pair runs through the production
     # CyclePipeline: deferred condition writes drain in the next kernel
     # window and the fused dispatches replay overlapped — decisions (and
     # the binding log) are parity-gated identical either way

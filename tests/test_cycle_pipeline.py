@@ -131,7 +131,8 @@ def test_pipeline_spans_and_device_busy():
     sched = Scheduler(store)
     pipeline = CyclePipeline(sched, enabled=True)
     res = pipeline.run_cycle(now=NOW)
-    assert res.device_busy_seconds > 0
+    # device busy time comes from a profiler trace, not the host clock
+    assert not hasattr(res, "device_busy_seconds")
     root = sched.tracer.roots(limit=1)[0]
     assert root.find("pack_incremental") is not None
     kernel = root.find("kernel")

@@ -148,7 +148,8 @@ def test_mesh_cycle_emits_shard_spans_and_gauges(cpu_devices):
     assert res.bound  # the fixture must actually schedule
     root = sched.tracer.roots(limit=1)[0]
     kernel = root.find("kernel")
-    shards = [s for s in kernel.children if s.name == "shard"]
+    # the per-shard markers ride the readback's sync span in the kernel
+    shards = kernel.find("sync").find_all("shard")
     assert len(shards) == 4
     assert [s.attributes["index"] for s in shards] == ["0", "1", "2", "3"]
     total_rows = sum(int(s.attributes["rows"]) for s in shards)

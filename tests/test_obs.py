@@ -390,12 +390,14 @@ class TestCycleInstrumentation:
         assert root.find("compile") is not None
         assert _counter(scheduler_metrics.COMPILE_CACHE_MISSES) == misses0 + 1
         assert _counter(scheduler_metrics.COMPILE_CACHE_HITS) == hits0
-        # per-binding spans under bind
-        bind_pods = root.find_all("bind_pod")
-        assert len(bind_pods) == 4
-        for bp in bind_pods:
-            assert {"reserve", "prebind"} == {c.name for c in bp.children}
-            assert bp.attributes["node"].startswith("node-")
+        # per-binding Reserve/PreBind time rides the bind span as summed
+        # attributes, not as per-pod spans
+        assert root.find_all("bind_pod") == []
+        bind = root.find("bind")
+        assert bind.attributes["bound"] == "4"
+        assert float(bind.attributes["reserve_ms"]) > 0
+        assert float(bind.attributes["prebind_ms"]) > 0
+        assert "vetoes" not in bind.attributes
         # duration consolidated through the root span, and the latency
         # histogram observed the cycle
         assert result.duration_seconds == root.duration_seconds > 0
@@ -412,6 +414,106 @@ class TestCycleInstrumentation:
         root2 = sched.tracer.roots()[-1]
         assert root2.find("kernel").attributes["compiled"] == "0"
         assert root2.find("compile") is None
+
+    @pytest.mark.parametrize("waves", [1, 4], ids=["serial", "chained"])
+    def test_cycle_skeleton_spans(self, waves):
+        """The root's own work sits under named depth-1 spans: queue,
+        plan and status; upload and sync under a kernel; a preempt span
+        where a no-fit pod evicts a lower-priority victim (under
+        wave_replay on the chained path); no per-pod bind spans."""
+        store = make_store(num_nodes=1)
+        for i in range(4):
+            running = Pod(
+                meta=ObjectMeta(name=f"low{i}", uid=f"low{i}",
+                                creation_timestamp=NOW),
+                spec=PodSpec(priority=100, node_name="node-0",
+                             requests=ResourceList.of(cpu=4000,
+                                                      memory=GIB)))
+            running.phase = "Running"
+            store.add(KIND_POD, running)
+        store.add(KIND_POD, Pod(
+            meta=ObjectMeta(name="vip", uid="vip", creation_timestamp=NOW),
+            spec=PodSpec(priority=9000,
+                         requests=ResourceList.of(cpu=4000, memory=GIB))))
+        sched = Scheduler(store, waves=waves)
+        result = sched.run_cycle(now=NOW)
+        assert [b.pod_key for b in result.bound] == ["default/vip"]
+        assert len(result.preempted_victims) == 1
+
+        root = sched.tracer.roots()[-1]
+        depth1 = [c.name for c in root.children]
+        assert {"queue", "prefilter", "plan", "status"} <= set(depth1)
+        assert root.find("queue").attributes["pods"] == "1"
+        assert root.find("plan").attributes["waves"] == str(waves)
+        kernels = root.find_all("kernel")
+        assert any(k.find("upload") is not None for k in kernels)
+        assert any(k.find("sync") is not None for k in kernels)
+        up = root.find("upload")
+        assert int(up.attributes["bytes"]) > 0
+        assert int(up.attributes["fields_put"]) > 0
+        evicting = [p for p in root.find_all("preempt")
+                    if p.attributes["victims"] != "0"]
+        assert len(evicting) == 1
+        assert evicting[0].attributes["pods"] == "1"
+        assert int(evicting[0].attributes["nodes_scanned"]) >= 1
+        if waves > 1:
+            replays = root.find_all("wave_replay")
+            assert any(evicting[0] in list(w.walk()) for w in replays)
+            assert "preempt" not in depth1
+        else:
+            assert evicting[0] in root.children
+        assert root.find_all("bind_pod") == []
+        assert root.find_all("reserve") == []
+        assert root.find_all("prebind") == []
+
+    def test_skeleton_annotations_on_the_profiler_clock(self, tmp_path):
+        """A jax.profiler capture of one cycle holds the root and its
+        depth-1 spans as host-plane annotations, at offsets from an
+        anchor that match the spans' start_mono within 1 ms; deeper
+        spans are not annotated."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData
+
+        store = make_store()
+        sched = Scheduler(store)
+        pend_pod(store, "warm")
+        sched.run_cycle(now=NOW)  # compile outside the capture
+        for i in range(3):
+            pend_pod(store, f"p{i}")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("test_obs_anchor"):
+                t_anchor = time.perf_counter()
+            sched.run_cycle(now=NOW + 1)
+        finally:
+            jax.profiler.stop_trace()
+        root = sched.tracer.roots()[-1]
+        files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = ProfileData.from_file(files[0])
+        starts = {}
+        for plane in pd.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    starts.setdefault(ev.name, []).append(int(ev.start_ns))
+        (a_ns,) = starts["test_obs_anchor"]
+        skeleton = [root] + root.children
+        assert {"cycle", "queue", "kernel", "bind", "status"} <= {
+            s.name for s in skeleton}
+        for sp in skeleton:
+            want = sp.start_mono - t_anchor
+            got = [(s - a_ns) / 1e9 for s in starts.get(sp.name, [])]
+            assert got, sp.name
+            assert min(abs(g - want) for g in got) < 1e-3, sp.name
+        assert root.find("upload") is not None
+        assert "upload" not in starts
 
     def test_empty_cycle_still_stamps_duration(self):
         """The old three-site duration assignment shipped 0.0 whenever a
