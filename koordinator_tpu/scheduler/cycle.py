@@ -2091,8 +2091,10 @@ class Scheduler:
             scheduler_metrics.DIAGNOSE_DEFERRED_DEPTH.set(
                 float(len(self._deferred_diagnose)))
             return
-        with self.tracer.span("diagnose", pods=str(len(items))):
-            self._diagnose_and_write(items, last, now, messages=messages)
+        with self.tracer.span("diagnose", pods=str(len(items))) as sp:
+            computed = self._diagnose_and_write(items, last, now,
+                                                messages=messages)
+            sp.attributes["computed"] = str(computed)
 
     def _stash_terms(self, keys, chosen_mask, terms_np) -> None:
         """KOORD_TPU_EXPLAIN=full: per-pod decision-time score attribution
@@ -2235,9 +2237,11 @@ class Scheduler:
         while self._deferred_diagnose:
             items, last, now, messages = self._deferred_diagnose.pop(0)
             with self.tracer.span("diagnose", pods=str(len(items)),
-                                  deferred="1"):
-                self._diagnose_and_write(items, last, now, deferred=True,
-                                         messages=messages, txn=txn)
+                                  deferred="1") as sp:
+                computed = self._diagnose_and_write(
+                    items, last, now, deferred=True, messages=messages,
+                    txn=txn)
+                sp.attributes["computed"] = str(computed)
         if txn is not None and txn.pending:
             with self.tracer.span("store_flush",
                                   writes=str(len(txn.pending))):
@@ -2247,8 +2251,15 @@ class Scheduler:
 
     def _diagnose_and_write(self, items, last, now: float,
                             deferred: bool = False, messages=None,
-                            txn: Optional[_DeferredFlushTxn] = None) -> None:
+                            txn: Optional[_DeferredFlushTxn] = None) -> int:
+        """Write PodScheduled=False for ``items``; returns how many
+        node-level stage-count vectors the host recompute computed. All
+        items read one packed state (``last``), so the node-level inputs
+        (``shared``) and the per-filter-row counts (``memo``) are built
+        once here and live no longer than this call."""
         shared = None  # node-level diagnosis state, built once per cycle
+        memo: Dict[bytes, np.ndarray] = {}
+        diagnosed = 0
         for pod, reason in items:
             msg = reason
             if messages is not None and pod.meta.key in messages:
@@ -2282,7 +2293,8 @@ class Scheduler:
                             if shared is None:
                                 shared = shared_state(fc, n_nodes)
                             msg = diagnose_unbound(fc, j, n_nodes,
-                                                   shared=shared)
+                                                   shared=shared, memo=memo)
+                            diagnosed += 1
                     except Exception:  # diagnosis must never wedge a cycle
                         logger.exception(
                             "unschedulability diagnosis failed for %s",
@@ -2324,6 +2336,10 @@ class Scheduler:
                 txn.put(patched)
             else:
                 self.store.update(KIND_POD, patched)
+        if diagnosed:
+            scheduler_metrics.DIAGNOSE_MEMO_MISSES.inc(len(memo))
+            scheduler_metrics.DIAGNOSE_MEMO_HITS.inc(diagnosed - len(memo))
+        return len(memo)
 
     # ------------------------------------------------------------------
     def _resolve_admission(self):

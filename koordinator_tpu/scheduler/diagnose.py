@@ -9,8 +9,12 @@ this module re-runs the SAME per-stage predicates in numpy against the
 batch's packed arrays — pre-batch state, one pod at a time — and
 aggregates per-stage failure counts into the upstream-style message.
 
-Cost: O(N x R) per diagnosed pod, run host-side only for pods that END a
-cycle unbound (typically few); the kernel pass itself is untouched.
+Cost: O(N x R) per distinct filter row per packed state. The node-level
+counts depend on the pod only through its filter row (_FilterRow), so a
+caller that diagnoses many pods against one state passes a memo and
+counts each distinct row once: a backlog flush diagnoses ~1,000 pods
+that share ~100 rows. The gang/quota PreFilter flags stay per pod. The
+kernel pass itself is untouched.
 
 Caveat, documented: the breakdown is computed against the CYCLE-START
 state (before in-batch placements), so a pod starved by earlier pods in
@@ -28,7 +32,7 @@ parity oracle tier-1 diffs the kernel counts against.
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -102,23 +106,61 @@ def shared_state(fc, num_nodes: int) -> dict:
     }
 
 
-def _stage_verdicts(fc, i: int, num_nodes: int, shared: dict = None):
-    """The per-stage verdicts behind one pod's diagnosis: a pod-level
-    PreFilter flag pair (gang invalid, quota exhausted) plus the per-node
-    reject masks keyed by EXPLAIN_STAGES label. Shared by the counts
-    oracle (host_stage_counts) and the feasibility view
-    (host_feasible_mask) so the two can never drift."""
-    inputs = fc.base
-    n = num_nodes
-    if shared is None:
-        shared = shared_state(fc, n)
-    alloc = shared["alloc"]
-    requested = shared["requested"]
-    node_ok = shared["node_ok"]
-    fit_req = np.asarray(inputs.fit_requests, np.float32)[i]
-    raw_req = np.asarray(fc.requests, np.float32)[i]
+class _FilterRow(NamedTuple):
+    """Everything the node-level Filter masks read of one pod row. The
+    masks (``_node_rejects``) take the pod ONLY through this row, and the
+    diagnosis memo keys on its bytes, so a field the masks come to read
+    enters the key by construction. The gang and quota ids stay out: they
+    feed the pod-level PreFilter flags alone."""
 
-    # ---- PreFilter stage (pod-level verdict flags; no node breakdown)
+    fit_req: np.ndarray
+    raw_req: np.ndarray
+    taint_mask: np.ndarray
+    is_prod: np.ndarray
+    is_daemonset: np.ndarray
+    port_wants: np.ndarray
+    vol_needed: np.ndarray
+    needs_bind: np.ndarray
+    cores_needed: np.ndarray
+    full_pcpus: np.ndarray
+    needs_numa: np.ndarray
+    aff_match: np.ndarray
+    anti_req: np.ndarray
+    aff_req: np.ndarray
+    spread_skew: np.ndarray
+
+    def key(self) -> bytes:
+        # every field has one dtype and shape within a packed state, so
+        # the concatenation is unambiguous there; equal bytes are equal
+        # values, hence equal masks
+        return b"".join(np.asarray(f).tobytes() for f in self)
+
+
+def _filter_row(fc, i: int) -> _FilterRow:
+    inputs = fc.base
+    return _FilterRow(
+        fit_req=np.asarray(inputs.fit_requests)[i].astype(np.float32),
+        raw_req=np.asarray(fc.requests)[i].astype(np.float32),
+        taint_mask=np.asarray(fc.pod_taint_mask)[i],
+        is_prod=np.asarray(inputs.is_prod)[i].astype(bool),
+        is_daemonset=np.asarray(inputs.is_daemonset)[i].astype(bool),
+        port_wants=np.asarray(fc.pod_port_wants)[i].astype(bool),
+        vol_needed=np.asarray(fc.vol_needed)[i].astype(np.float32),
+        needs_bind=np.asarray(fc.needs_bind)[i].astype(bool),
+        cores_needed=np.asarray(fc.cores_needed)[i],
+        full_pcpus=np.asarray(fc.full_pcpus)[i].astype(bool),
+        needs_numa=np.asarray(fc.needs_numa)[i].astype(bool),
+        aff_match=np.asarray(fc.pod_aff_match)[i].astype(bool),
+        anti_req=np.asarray(fc.pod_anti_req)[i].astype(bool),
+        aff_req=np.asarray(fc.pod_aff_req)[i].astype(bool),
+        spread_skew=np.asarray(fc.pod_spread_skew)[i].astype(np.float32),
+    )
+
+
+def _prefilter_flags(fc, i: int, raw_req: np.ndarray):
+    """The pod-level PreFilter verdicts (gang invalid, quota exhausted):
+    no node breakdown, and the only part of a diagnosis that reads the
+    pod's gang and quota ids."""
     gang_bad = False
     quota_bad = False
     gang_id = int(np.asarray(fc.gang_id)[i])
@@ -136,12 +178,25 @@ def _stage_verdicts(fc, i: int, num_nodes: int, shared: dict = None):
             if bad.any():
                 quota_bad = True
                 break
+    return gang_bad, quota_bad
 
-    # ---- Filter stages, counted per node
+
+def _node_rejects(fc, row: _FilterRow, num_nodes: int,
+                  shared: dict) -> Dict[str, np.ndarray]:
+    """The per-node Filter reject masks keyed by EXPLAIN_STAGES label: a
+    pure function of the packed state ``fc`` (node-level arrays and
+    ``shared``) and the pod's filter ``row``."""
+    n = num_nodes
+    alloc = shared["alloc"]
+    requested = shared["requested"]
+    node_ok = shared["node_ok"]
+    fit_req = row.fit_req
+    raw_req = row.raw_req
+
     reasons: Dict[str, np.ndarray] = {}
     reasons["node not schedulable"] = ~node_ok
     # admission bitmask: taints + nodeSelector/affinity + volume topology
-    mask = int(np.asarray(fc.pod_taint_mask)[i])
+    mask = int(row.taint_mask)
     group = np.asarray(fc.node_taint_group)[:n]
     reasons["taint/selector/volume-topology mismatch"] = (
         ((mask >> group) & 1) == 0)
@@ -150,36 +205,34 @@ def _stage_verdicts(fc, i: int, num_nodes: int, shared: dict = None):
         (fit_req[None, :] > 0) & (requested + fit_req[None, :] > alloc)
     ).any(axis=1)
     # LoadAware thresholds (node rows precomputed in shared_state)
-    is_prod = bool(np.asarray(inputs.is_prod)[i])
-    is_ds = bool(np.asarray(inputs.is_daemonset)[i])
-    la_rej = shared["rej_pr"] if is_prod else shared["rej_np"]
+    la_rej = shared["rej_pr"] if bool(row.is_prod) else shared["rej_np"]
     reasons["node load over threshold"] = (
-        la_rej if not is_ds else np.zeros(n, bool))
+        la_rej if not bool(row.is_daemonset) else np.zeros(n, bool))
     # NodePorts
-    wants = np.asarray(fc.pod_port_wants, bool)[i]
+    wants = row.port_wants
     if wants.any():
         used_ports = np.asarray(fc.port_used, np.float32)[:n]
         reasons["hostPort in use"] = (
             used_ports[:, wants] > 0).any(axis=1)
     # CSI volume limits (volume-group row selects new attachments)
-    vn_row = np.asarray(fc.vol_needed, np.float32)[i]
+    vn_row = row.vol_needed
     if (vn_row > 0).any():
         vg = np.asarray(fc.node_vol_group)[:n]
         vn = vn_row[vg]
         reasons["CSI volume limit exceeded"] = (
             (vn > 0) & (np.asarray(fc.vol_free, np.float32)[:n] < vn))
     # cpuset capacity
-    if bool(np.asarray(fc.needs_bind)[i]):
-        cores = float(np.asarray(fc.cores_needed)[i])
+    if bool(row.needs_bind):
+        cores = float(row.cores_needed)
         bind_free = np.asarray(fc.bind_free, np.float32)[:n]
         has_topo = np.asarray(fc.has_topology, bool)[:n]
         cpc = np.maximum(np.asarray(fc.cpus_per_core, np.float32)[:n], 1.0)
         bad = ~has_topo | (cores > bind_free)
-        if bool(np.asarray(fc.full_pcpus)[i]):
+        if bool(row.full_pcpus):
             bad |= np.remainder(cores, cpc) != 0
         reasons["insufficient bindable CPUs"] = bad
     # NUMA topology
-    if bool(np.asarray(fc.needs_numa)[i]):
+    if bool(row.needs_numa):
         numa_free = np.asarray(fc.numa_free, np.float32)[:n]
         policy = np.asarray(fc.numa_policy)[:n]
         per_zone_fit = (
@@ -200,21 +253,20 @@ def _stage_verdicts(fc, i: int, num_nodes: int, shared: dict = None):
         cover = np.asarray(fc.anti_cover, np.float32)[:n]
         exists = np.asarray(fc.aff_exists, bool)
         taint_ok = ~reasons["taint/selector/volume-topology mismatch"]
-        skew_row = np.asarray(fc.pod_spread_skew, np.float32)[i]
         for t in range(T):
-            match_t = bool(np.asarray(fc.pod_aff_match)[i, t])
-            if bool(np.asarray(fc.pod_anti_req)[i, t]):
+            match_t = bool(row.aff_match[t])
+            if bool(row.anti_req[t]):
                 aff_bad |= count[:, t] > 0
             if match_t:
                 aff_bad |= cover[:, t] > 0
-            if bool(np.asarray(fc.pod_aff_req)[i, t]):
+            if bool(row.aff_req[t]):
                 # bootstrap admits a self-matching first replica only when
                 # NO matching pod exists anywhere; otherwise the node needs
                 # a matching pod in a valid domain
                 bootstrap = match_t and not exists[t]
                 if not bootstrap:
                     aff_bad |= ~((dom[:, t] >= 0) & (count[:, t] > 0))
-            skew = float(skew_row[t])
+            skew = float(row.spread_skew[t])
             if skew > 0:
                 dom_valid = dom[:, t] >= 0
                 eligible = dom_valid & taint_ok
@@ -225,28 +277,52 @@ def _stage_verdicts(fc, i: int, num_nodes: int, shared: dict = None):
                              & (count[:, t] + self_m - min_count <= skew))
         reasons["affinity/anti-affinity/spread mismatch"] = aff_bad
 
-    return gang_bad, quota_bad, reasons
+    return reasons
+
+
+def _node_counts(fc, row: _FilterRow, num_nodes: int, shared: dict,
+                 memo: Optional[dict]) -> np.ndarray:
+    """[NUM_EXPLAIN_STAGES] uint32 of per-stage rejected-node counts for
+    ``row`` (gang and quota slots 0). With ``memo`` the vector is computed
+    once per distinct row: the caller scopes the memo to one packed state,
+    so no entry outlives the state it was counted against. A memoized
+    vector is shared, so it is stored read-only."""
+    key = row.key() if memo is not None else None
+    if key is not None:
+        hit = memo.get(key)
+        if hit is not None:
+            return hit
+    reasons = _node_rejects(fc, row, num_nodes, shared)
+    counts = np.zeros(NUM_EXPLAIN_STAGES, np.uint32)
+    for s, label in enumerate(EXPLAIN_STAGES):
+        bad = reasons.get(label)
+        if bad is not None:
+            counts[s] = _count(bad)
+    if key is not None:
+        counts.setflags(write=False)
+        memo[key] = counts
+    return counts
 
 
 def host_stage_counts(fc, i: int, num_nodes: int,
-                      shared: dict = None) -> np.ndarray:
+                      shared: dict = None,
+                      memo: Optional[dict] = None) -> np.ndarray:
     """[NUM_EXPLAIN_STAGES] uint32 for pod row ``i`` of FullChainInputs
     ``fc``: per-stage rejected-node counts over the first ``num_nodes``
     real (unpadded) nodes plus the gang/quota PreFilter verdict flags —
     the host-numpy oracle the kernel's on-device attribution is diffed
     against. Pass ``shared`` (shared_state) when diagnosing many pods of
-    one batch."""
-    gang_bad, quota_bad, reasons = _stage_verdicts(fc, i, num_nodes,
-                                                  shared=shared)
-    counts = np.zeros(NUM_EXPLAIN_STAGES, np.uint32)
+    one batch, and ``memo`` (a dict, fresh for each packed state) to
+    count each distinct filter row once."""
+    if shared is None:
+        shared = shared_state(fc, num_nodes)
+    row = _filter_row(fc, i)
+    gang_bad, quota_bad = _prefilter_flags(fc, i, row.raw_req)
+    counts = _node_counts(fc, row, num_nodes, shared, memo).copy()
     if gang_bad:
         counts[EXPLAIN_STAGE_GANG] = 1
     if quota_bad:
         counts[EXPLAIN_STAGE_QUOTA] = 1
-    for s, label in enumerate(EXPLAIN_STAGES):
-        bad = reasons.get(label)
-        if bad is not None:
-            counts[s] = _count(bad)
     return counts
 
 
@@ -258,10 +334,12 @@ def host_feasible_mask(fc, i: int, num_nodes: int,
     reports. The degradation ladder's host-fallback pass
     (scheduler/degrade.host_fallback_schedule) schedules against this
     view when the device dispatch is down."""
-    gang_bad, quota_bad, reasons = _stage_verdicts(fc, i, num_nodes,
-                                                  shared=shared)
-    if gang_bad or quota_bad:
+    row = _filter_row(fc, i)
+    if any(_prefilter_flags(fc, i, row.raw_req)):
         return np.zeros(num_nodes, bool)
+    if shared is None:
+        shared = shared_state(fc, num_nodes)
+    reasons = _node_rejects(fc, row, num_nodes, shared)
     feasible = np.ones(num_nodes, bool)
     for bad in reasons.values():
         feasible &= ~np.asarray(bad, bool)
@@ -269,10 +347,24 @@ def host_feasible_mask(fc, i: int, num_nodes: int,
 
 
 def diagnose_unbound(fc, i: int, num_nodes: int,
-                     shared: dict = None) -> str:
+                     shared: dict = None,
+                     memo: Optional[dict] = None) -> str:
     """Upstream-style message for pod row ``i`` of FullChainInputs ``fc``:
     the legacy host-numpy recompute path — host counts through the shared
     formatter. The explain-enabled cycle driver formats KERNEL counts with
-    the same formatter instead; tier-1 pins the two string-for-string."""
+    the same formatter instead; tier-1 pins the two string-for-string.
+
+    A gang or quota PreFilter verdict returns its message before any
+    node-level count: format_stage_counts short-circuits on those flags,
+    so the message is the one the full vector formats to. ``memo`` as in
+    host_stage_counts."""
+    row = _filter_row(fc, i)
+    gang_bad, quota_bad = _prefilter_flags(fc, i, row.raw_req)
+    if gang_bad:
+        return GANG_MESSAGE
+    if quota_bad:
+        return QUOTA_MESSAGE
+    if shared is None:
+        shared = shared_state(fc, num_nodes)
     return format_stage_counts(
-        host_stage_counts(fc, i, num_nodes, shared=shared), num_nodes)
+        _node_counts(fc, row, num_nodes, shared, memo), num_nodes)

@@ -307,3 +307,15 @@ DIAGNOSE_DEFERRED_DEPTH = REGISTRY.gauge(
     "koord_scheduler_diagnose_deferred_depth",
     "Deferred diagnose entries currently queued",
 )
+DIAGNOSE_MEMO_HITS = REGISTRY.counter(
+    "koord_scheduler_diagnose_memo_hits_total",
+    "Host unschedulability diagnoses that ran no node-level stage "
+    "recompute: the pod's filter row was already counted against the "
+    "same packed state, or a gang/quota PreFilter verdict decided the "
+    "message",
+)
+DIAGNOSE_MEMO_MISSES = REGISTRY.counter(
+    "koord_scheduler_diagnose_memo_misses_total",
+    "Host unschedulability diagnoses that ran the node-level stage "
+    "recompute (one per distinct filter row per packed state)",
+)

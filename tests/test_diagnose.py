@@ -19,20 +19,23 @@ from koordinator_tpu.models.full_chain import (
 )
 from koordinator_tpu.models.scheduler_model import ScheduleInputs
 from koordinator_tpu.ops.loadaware import LoadAwareArgs
+from koordinator_tpu.scheduler.cycle import _WaveStateMirror
 from koordinator_tpu.scheduler.diagnose import (
     GANG_MESSAGE,
     QUOTA_MESSAGE,
     diagnose_unbound,
     format_stage_counts,
     host_stage_counts,
+    shared_state,
 )
 
 N, P, R, T, K, PT = 3, 1, 2, 1, 2, 1
 
 
-def make_fc(**over) -> FullChainInputs:
+def make_fc(N=N, P=P, T=T, PT=PT, **over) -> FullChainInputs:
     """A minimal 1-pod x 3-node batch where EVERY stage passes; each test
-    flips exactly the arrays that trigger its reason branch."""
+    flips exactly the arrays that trigger its reason branch. The sizes
+    default to the module's and may be raised for batch tests."""
     f32, i32 = np.float32, np.int32
 
     base = ScheduleInputs(
@@ -241,3 +244,181 @@ def test_stage_taxonomy_matches_legacy_labels():
         "NUMA topology cannot fit",
         "affinity/anti-affinity/spread mismatch",
     )
+
+
+# ---------------------------------------------------------------------------
+# the per-state memo of node-level counts, keyed on the pod's filter row
+# ---------------------------------------------------------------------------
+
+BN, BP, BT, BPT = 64, 200, 2, 2
+
+
+def _shape(fit=(2.0, 4.0), req=None, taint=1.0, prod=False, ds=False,
+           ports=(0, 0), vol=0.0, bind=False, cores=0.0, full_pcpus=False,
+           numa=False, aff_match=(0, 0), anti=(0, 0), aff_req=(0, 0),
+           skew=(0, 0)):
+    return dict(
+        fit_requests=fit, requests=fit if req is None else req,
+        pod_taint_mask=taint, is_prod=prod, is_daemonset=ds,
+        pod_port_wants=ports, vol_needed=[vol], needs_bind=bind,
+        cores_needed=cores, full_pcpus=full_pcpus, needs_numa=numa,
+        pod_aff_match=aff_match, pod_anti_req=anti, pod_aff_req=aff_req,
+        pod_spread_skew=skew)
+
+
+# Distinct filter rows. For every field the node-level masks read, two
+# shapes differ in that field alone and reject different node counts,
+# so a memo key that missed the field would serve one shape the other's
+# counts: plain, prod, daemonset, hostPort, CSI volume, a taint miss,
+# more resources, anti-affinity, required affinity with and without a
+# self match, spread with and without a skew, LSR cpusets (cores, full
+# pcpus, no bind) and NUMA pods (same fit, other requests)
+SHAPES = [
+    _shape(),
+    _shape(prod=True),
+    _shape(ds=True),
+    _shape(ports=(1, 0)),
+    _shape(vol=2.0),
+    _shape(taint=2.0),
+    _shape(fit=(12.0, 4.0), req=(2.0, 4.0)),
+    _shape(anti=(0, 1)),
+    _shape(aff_req=(1, 0)),
+    _shape(aff_req=(1, 0), aff_match=(1, 0)),
+    _shape(aff_match=(0, 1)),
+    _shape(aff_match=(0, 1), skew=(0, 1.0)),
+    _shape(cores=4.0),
+    _shape(bind=True, cores=4.0),
+    _shape(bind=True, cores=2.0),
+    _shape(bind=True, cores=3.0),
+    _shape(bind=True, cores=3.0, full_pcpus=True),
+    _shape(numa=True),
+    _shape(numa=True, req=(6.0, 6.0)),
+    # only ever diagnosed with a PreFilter verdict (quota 0): the message
+    # path must not count it
+    _shape(fit=(1.0, 1.0)),
+]
+
+
+def _batch(seed: int = 7):
+    """200 pods of the SHAPES on 64 random nodes. Gangs 1 and 3 are
+    invalid; quota 0 has no room, quota 2 sits under it (exhausted by
+    its ancestor), quota 1 is ample. Every shape but the last has pods
+    free of both flags; the last has quota-0 pods alone. Returns (fc,
+    shape index per pod, PreFilter-flagged mask)."""
+    rng = np.random.default_rng(seed)
+    f32 = np.float32
+    shape_of = rng.integers(0, len(SHAPES), BP)
+    gang_id = np.where(rng.random(BP) < 0.3, rng.integers(0, 4, BP), -1)
+    quota_id = np.where(rng.random(BP) < 0.5, rng.integers(0, 3, BP), -1)
+    # every shape has quota-exhausted, gang-invalid and free pods
+    for s in range(len(SHAPES)):
+        shape_of[3 * s:3 * s + 3] = s
+        gang_id[3 * s:3 * s + 3] = (-1, -1, 1)
+        quota_id[3 * s:3 * s + 3] = (-1, 0, -1)
+    quota_id[shape_of == len(SHAPES) - 1] = 0
+    rows = {k: np.asarray([SHAPES[s][k] for s in shape_of])
+            for k in SHAPES[0]}
+    gang_valid = np.asarray([True, False, True, False])
+    flagged = (((gang_id >= 0) & ~gang_valid[np.maximum(gang_id, 0)])
+               | (quota_id == 0) | (quota_id == 2))
+    alloc = rng.choice([16.0, 32.0, 64.0], (BN, 2)).astype(f32)
+    fc = make_fc(
+        N=BN, P=BP, T=BT, PT=BPT,
+        fit_requests=rows["fit_requests"].astype(f32),
+        is_prod=rows["is_prod"], is_daemonset=rows["is_daemonset"],
+        allocatable=alloc,
+        requested=(alloc * rng.random((BN, 2))).astype(f32),
+        node_ok=rng.random(BN) > 0.1,
+        la_has_filter_usage=rng.random(BN) < 0.5,
+        la_filter_usage=(alloc * rng.random((BN, 2))).astype(f32),
+        la_filter_thresholds=np.full((BN, 2), 70.0, f32),
+        la_prod_thresholds=np.full((BN, 2), 50.0, f32),
+        la_prod_pod_usage=(alloc * rng.random((BN, 2))).astype(f32),
+        requests=rows["requests"].astype(f32),
+        gang_id=gang_id.astype(np.int32),
+        quota_id=quota_id.astype(np.int32),
+        needs_numa=rows["needs_numa"], needs_bind=rows["needs_bind"],
+        cores_needed=rows["cores_needed"].astype(f32),
+        full_pcpus=rows["full_pcpus"],
+        pod_taint_mask=rows["pod_taint_mask"].astype(f32),
+        pod_aff_req=rows["pod_aff_req"].astype(bool),
+        pod_anti_req=rows["pod_anti_req"].astype(bool),
+        pod_aff_match=rows["pod_aff_match"].astype(bool),
+        pod_spread_skew=rows["pod_spread_skew"].astype(f32),
+        pod_ppref_mask=np.zeros((BP, BT), bool),
+        pod_port_wants=rows["pod_port_wants"].astype(bool),
+        vol_needed=rows["vol_needed"].astype(f32),
+        node_taint_group=rng.integers(0, 2, BN).astype(np.int32),
+        aff_dom=rng.integers(-1, 4, (BN, BT)).astype(f32),
+        aff_count=rng.integers(0, 3, (BN, BT)).astype(f32),
+        anti_cover=(rng.random((BN, BT)) < 0.3).astype(f32),
+        aff_exists=np.asarray([True, False]),
+        ppref_w=np.zeros((1, BT), f32),
+        port_used=(rng.random((BN, BPT)) < 0.3).astype(f32),
+        vol_free=rng.integers(0, 4, BN).astype(f32),
+        numa_free=rng.choice([2.0, 4.0, 8.0], (BN, K, 2)).astype(f32),
+        numa_capacity=np.full((BN, K, 2), 8.0, f32),
+        numa_policy=rng.integers(0, 3, BN).astype(np.int32),
+        has_topology=rng.random(BN) > 0.2,
+        bind_free=rng.choice([2.0, 4.0, 8.0], BN).astype(f32),
+        cpus_per_core=rng.choice([1.0, 2.0], BN).astype(f32),
+        quota_ancestors=np.asarray([[0, -1], [1, -1], [2, 0]], np.int32),
+        quota_used=np.zeros((3, 2), f32),
+        quota_runtime=np.asarray([[0.0, 0.0], [1e6, 1e6], [1e6, 1e6]],
+                                 f32),
+        gang_min_member=np.ones(4, f32),
+        gang_assumed=np.zeros(4, f32),
+        gang_valid=gang_valid,
+        gang_group_id=np.arange(4, dtype=np.int32),
+    )
+    return fc, shape_of, flagged
+
+
+def test_memo_counts_match_oracle_per_row():
+    """One memo across every row of a state gives the oracle's counts for
+    each row, gang and quota slots included, and computes one node-level
+    vector per distinct filter row; the message path computes none for
+    PreFilter-flagged pods and formats the oracle's message for all."""
+    fc, shape_of, flagged = _batch()
+    assert flagged.any() and (~flagged).any()
+    shared = shared_state(fc, BN)
+    oracle = [host_stage_counts(fc, i, BN) for i in range(BP)]
+    stages = np.stack(oracle)[:, :len(EXPLAIN_STAGES)]
+    # the batch reaches every Filter stage and both PreFilter flags
+    assert (stages.sum(axis=0) > 0).all(), stages.sum(axis=0)
+    assert any(c[EXPLAIN_STAGE_GANG] for c in oracle)
+    assert any(c[EXPLAIN_STAGE_QUOTA] for c in oracle)
+
+    memo = {}
+    for i in range(BP):
+        got = host_stage_counts(fc, i, BN, shared=shared, memo=memo)
+        assert np.array_equal(got, oracle[i]), (i, got, oracle[i])
+    assert len(memo) == len(set(shape_of.tolist())) == len(SHAPES)
+
+    memo = {}
+    for i in range(BP):
+        msg = diagnose_unbound(fc, i, BN, shared=shared, memo=memo)
+        assert msg == format_stage_counts(oracle[i], BN), i
+    assert len(memo) == len(set(shape_of[~flagged].tolist())) == (
+        len(SHAPES) - 1)
+
+
+def test_memo_is_per_state():
+    """Two states of one batch that differ by a committed placement, each
+    diagnosed with its own memo, give each state's oracle counts; the
+    placement moves some row's counts, so a memo carried from one state
+    into the other would serve stale ones."""
+    fc_a, _shape_of, _flagged = _batch(seed=11)
+    mirror = _WaveStateMirror(fc_a)
+    for i, node in ((3, 5), (9, 5), (12, 17), (15, 17)):
+        mirror.commit(i, node, 0)
+    fc_b = mirror.patched_fc()
+    for fc in (fc_a, fc_b):
+        shared, memo = shared_state(fc, BN), {}
+        for i in range(BP):
+            got = host_stage_counts(fc, i, BN, shared=shared, memo=memo)
+            assert np.array_equal(got, host_stage_counts(fc, i, BN)), i
+    moved = sum(not np.array_equal(host_stage_counts(fc_a, i, BN),
+                                   host_stage_counts(fc_b, i, BN))
+                for i in range(BP))
+    assert moved > 0

@@ -161,6 +161,39 @@ def test_deferred_diagnose_metrics():
     assert gauge.get() == 0.0, "flush must drain the deferred queue"
 
 
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["deferred", "direct"])
+def test_diagnose_memo_metrics(pipelined):
+    """The memo's hit and miss counters and each diagnose span's
+    computed= agree with the pods the flushes diagnosed: one miss per
+    node-level vector computed, one hit per other diagnosed pod. A
+    saturated world leaves every unbound pod to the host diagnosis."""
+    hits = scheduler_metrics.DIAGNOSE_MEMO_HITS
+    misses = scheduler_metrics.DIAGNOSE_MEMO_MISSES
+    h0, m0 = hits.get() or 0.0, misses.get() or 0.0
+    state, store = make_world(nodes=6, pods=200, seed=9)
+    sched = Scheduler(store, waves=1, explain="off")
+    pipeline = CyclePipeline(sched, enabled=pipelined)
+    _run_rounds(sched, store, state, rounds=2)
+    pipeline.flush()
+    spans = []
+
+    def walk(sp):
+        if sp.name == "diagnose":
+            spans.append(sp.attributes)
+        for child in sp.children:
+            walk(child)
+
+    for root in sched.tracer.roots():
+        walk(root)
+    assert spans and all(("deferred" in a) == pipelined for a in spans)
+    pods = sum(int(a["pods"]) for a in spans)
+    computed = sum(int(a["computed"]) for a in spans)
+    assert all(int(a["computed"]) <= int(a["pods"]) for a in spans)
+    assert (misses.get() or 0.0) - m0 == computed > 0
+    assert (hits.get() or 0.0) - h0 == pods - computed > 0
+
+
 # ---------------------------------------------------------------------------
 # env plumbing
 # ---------------------------------------------------------------------------
