@@ -233,8 +233,8 @@ def _warmup_summary(sched) -> str:
     sched.warmup.join()
     st = sched.warmup.stats
     return ",".join(
-        [f"{k}={st[k]}" for k in ("rungs", "warmed", "built", "skipped",
-                                  "failed", "invalidated")]
+        [f"{k}={st[k]}" for k in ("rungs", "warmed", "skipped", "failed",
+                                  "invalidated")]
         + [f"s={st['seconds']:.3f}"])
 
 

@@ -192,7 +192,7 @@ def main(argv) -> int:
     print(f"ok coldstart: logs identical "
           f"({cold['binding_log_sha256'][:16]}…), warm restart "
           f"{warm_wall:.2f}s < cold {cold_wall:.2f}s, warm-up "
-          f"{wu.get('warmed', 0)}+{wu.get('built', 0)}/{wu.get('rungs', 0)}"
+          f"{wu.get('warmed', 0)}/{wu.get('rungs', 0)}"
           f" rungs, 0 steady-state recompiles", file=sys.stderr)
     return 0
 

@@ -633,6 +633,34 @@ def build_full_chain_step(args: LoadAwareArgs, num_gangs: int, num_groups: int,
     return jax.jit(full_chain_xla_explain) if jit else full_chain_xla_explain
 
 
+def full_chain_vmem_bytes(fc: FullChainInputs) -> int:
+    """The Pallas full-chain kernel's scoped-VMEM estimate at ``fc``'s
+    shapes (ops/pallas_full_chain.estimate_vmem_bytes). Over the device
+    kind's budget, the selector below keeps the batch on the XLA step."""
+    from koordinator_tpu.ops.pallas_full_chain import estimate_vmem_bytes
+
+    return estimate_vmem_bytes(
+        fc.base.allocatable.shape[0], fc.base.fit_requests.shape[1],
+        fc.numa_free.shape[1], fc.quota_used.shape[0], fc.aff_dom.shape[1],
+        fc.pref_scores.shape[1], fc.port_used.shape[1],
+        fc.img_scores.shape[1])
+
+
+def node_axis_vmem_bytes(n_nodes: int) -> int:
+    """``full_chain_vmem_bytes`` at ``n_nodes``' bucket with the fewest
+    axes a pack keeps (scheduler/snapshot.reduce_to_active_axes: the
+    pods, cpu and memory axes, one NUMA zone; one quota row, no affinity,
+    preference, port or image columns). The estimate grows with every
+    axis, so this is a lower bound for each batch over that many nodes:
+    over the budget, no batch of the cluster fits the Pallas kernel on
+    one chip. The scheduler's size rule (parallel/mesh.size_rule_shards)
+    reads it, so the mesh follows the node axis alone."""
+    from koordinator_tpu.ops.packing import bucket_size
+    from koordinator_tpu.ops.pallas_full_chain import estimate_vmem_bytes
+
+    return estimate_vmem_bytes(bucket_size(n_nodes), 3, 1, 1)
+
+
 def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
                                num_groups: int, active_axes=None,
                                vmem_budget_bytes=None, kernel: str = "auto",
@@ -699,7 +727,6 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         SMEM_BUDGET_BYTES,
         build_pallas_full_chain_step,
         estimate_smem_bytes,
-        estimate_vmem_bytes,
     )
 
     budget = (pc.default_vmem_budget() if vmem_budget_bytes is None
@@ -716,20 +743,14 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         return pallas_steps[enable_volumes]
 
     def step(fc: FullChainInputs):
-        P, R = fc.base.fit_requests.shape
-        N = fc.base.allocatable.shape[0]
-        K = fc.numa_free.shape[1]
-        G = fc.quota_used.shape[0]
+        P = fc.base.fit_requests.shape[0]
         T = fc.aff_dom.shape[1]
-        S = fc.pref_scores.shape[1]
-        PT = fc.port_used.shape[1]
-        SI = fc.img_scores.shape[1]
         VG = fc.vol_needed.shape[1]
         S2 = fc.ppref_w.shape[0] if T else 0
         # VMEM budget first: a batch bound for the XLA step anyway must
         # not pay the vol-flag resolution (which can cost a D2H readback
         # for fresh device-resident arrays)
-        if estimate_vmem_bytes(N, R, K, G, T, S, PT, SI) > budget:
+        if full_chain_vmem_bytes(fc) > budget:
             step.last_backend = "xla"
             return xla_step(fc)
         # the snapshot builder hands HOST (numpy) arrays, so this check

@@ -54,16 +54,36 @@ def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     return Mesh(dev_array, axis_names=("pods", "nodes"))
 
 
-def mesh_from_env(env_value: Optional[str] = None) -> Optional[Mesh]:
-    """KOORD_TPU_MESH=<ndev>|auto selects the production mesh-backed
-    dispatch path (scheduler/cycle.py): "auto" takes every visible device,
-    an integer takes a prefix of `jax.devices()`. Unset/0/1-device-visible
-    "auto"/"off" return None — the single-device path. A request for more
-    devices than exist fails loudly (a silently-smaller mesh would make
-    capacity planning lie)."""
+def size_rule_shards(vmem_bytes: int, n_devices: int,
+                     budget: Optional[int]) -> int:
+    """The default path's mesh choice: shard the node axis over every
+    visible chip only where the one-chip Pallas kernel cannot hold the
+    cluster, i.e. its VMEM estimate at the cluster's node bucket
+    (``models.full_chain.node_axis_vmem_bytes``) is over the device
+    kind's budget — the selector's own test, at the fewest axes a batch
+    keeps. No budget (off TPU) or one chip: 1, the single-device path."""
+    if budget is None or n_devices < 2 or vmem_bytes <= budget:
+        return 1
+    return n_devices
+
+
+def mesh_env_value() -> str:
+    """KOORD_TPU_MESH as set, "" when unset: an explicit value overrides
+    the size rule (``size_rule_shards``)."""
     import os
 
-    raw = (os.environ.get("KOORD_TPU_MESH", "") if env_value is None
+    return os.environ.get("KOORD_TPU_MESH", "").strip().lower()
+
+
+def mesh_from_env(env_value: Optional[str] = None) -> Optional[Mesh]:
+    """KOORD_TPU_MESH=<ndev>|auto pins the production mesh-backed
+    dispatch path (scheduler/cycle.py): "auto" takes every visible device,
+    an integer takes a prefix of `jax.devices()`. Unset/0/1-device-visible
+    "auto"/"off" return None — the single-device path (unset, the
+    scheduler applies the size rule instead). A request for more
+    devices than exist fails loudly (a silently-smaller mesh would make
+    capacity planning lie)."""
+    raw = (mesh_env_value() if env_value is None
            else str(env_value)).strip().lower()
     if raw in ("", "0", "off", "false", "no"):
         return None

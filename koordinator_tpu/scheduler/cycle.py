@@ -522,17 +522,14 @@ class Scheduler:
         self.config = (_dc.replace(base, load_aware=args)
                        if args is not None else base)
         self.config.validate()
-        # the Pallas selector's scoped-VMEM budget, looked up here and not
-        # in _get_step: an unknown TPU kind must fail construction, not
-        # raise inside the dispatch ladder, which would retry and demote
-        # the device away while pods keep binding
-        import jax
+        # the Pallas selector's scoped-VMEM budget (None off TPU), looked
+        # up here and not in _get_step: an unknown TPU kind must fail
+        # construction, not raise inside the dispatch ladder, which would
+        # retry and demote the device away while pods keep binding. The
+        # mesh size rule compares with the same budget.
+        from koordinator_tpu.ops import pallas_common
 
-        self._vmem_budget = None
-        if jax.default_backend() == "tpu":
-            from koordinator_tpu.ops import pallas_common
-
-            self._vmem_budget = pallas_common.default_vmem_budget()
+        self._vmem_budget = pallas_common.default_vmem_budget()
         self.store = store
         self.args = self.config.load_aware
         self.scheduler_name = scheduler_name
@@ -732,16 +729,30 @@ class Scheduler:
         self._sidecar_client = (
             SidecarClient(sidecar_address) if sidecar_address else None)
         self.sidecar_fallbacks = 0
-        # mesh-backed dispatch (KOORD_TPU_MESH=<ndev>|auto): node-state
-        # tensors shard over the device mesh (parallel/mesh.py), the
-        # filter/score rows compute shard-locally and the argmax reduces
-        # over ICI — the cluster sizes one chip cannot hold. An explicit
-        # argument pins it (int/"auto"/"off"/a jax Mesh); None reads the
-        # env. The gRPC sidecar protocol is single-device, so a sidecar
+        # mesh-backed dispatch: node-state tensors shard over the device
+        # mesh (parallel/mesh.py), the filter/score rows compute
+        # shard-locally and the argmax reduces over ICI — the cluster
+        # sizes one chip's kernel cannot hold. An explicit argument
+        # (int/"auto"/"off"/a jax Mesh) or KOORD_TPU_MESH pins it;
+        # otherwise the size rule chooses it (parallel/mesh.
+        # size_rule_shards) from the node axis: the store's nodes here,
+        # each new packed node bucket at the resize gate (_resize_gate).
+        # The gRPC sidecar protocol is single-device, so a sidecar
         # demotes the mesh off.
-        from koordinator_tpu.parallel.mesh import mesh_from_env
+        from koordinator_tpu.parallel.mesh import (
+            mesh_env_value,
+            mesh_from_env,
+        )
 
-        if mesh is None:
+        self._mesh_by_size = (mesh is None and not mesh_env_value()
+                              and self._sidecar_client is None)
+        self._size_rule_bucket = 0
+        if self._mesh_by_size:
+            # the nodes a pack keeps (_cluster_state): its node bucket
+            self.mesh = self._size_rule_mesh(sum(
+                1 for n in self.store.list(KIND_NODE)
+                if not n.unschedulable))
+        elif mesh is None:
             self.mesh = mesh_from_env()
         elif isinstance(mesh, (int, str)):
             self.mesh = mesh_from_env(env_value=mesh)
@@ -850,6 +861,64 @@ class Scheduler:
             self.warmup.start()
 
     # ------------------------------------------------------------------
+    def _size_rule_mesh(self, n_nodes: int):
+        """The mesh the size rule picks for a cluster of ``n_nodes``:
+        every visible device, or None. Start-up and the resize gate both
+        ask here; the node bucket it read is kept for the gate."""
+        import jax
+
+        from koordinator_tpu.models.full_chain import node_axis_vmem_bytes
+        from koordinator_tpu.ops.packing import bucket_size
+        from koordinator_tpu.parallel.mesh import make_mesh, size_rule_shards
+
+        self._size_rule_bucket = bucket_size(n_nodes)
+        if self._vmem_budget is None:
+            return None
+        devices = jax.devices()
+        n = size_rule_shards(node_axis_vmem_bytes(n_nodes), len(devices),
+                             self._vmem_budget)
+        return make_mesh(devices[:n]) if n > 1 else None
+
+    def _resize_gate(self, n_nodes: int) -> None:
+        """The size rule again when a packed batch's node bucket differs
+        from the one last read: a cluster that grew past one chip's
+        kernel shards from this dispatch on, one that shrank below it
+        returns to the single device. What a batch asks for (affinity
+        domains, ports, images) never moves it. Only a mesh the rule
+        chose moves, never a pinned one, and only on a change: the new
+        placement becomes the configured mesh the ladder applies, and
+        the device mirror and step keys follow it."""
+        from koordinator_tpu.ops.packing import bucket_size
+
+        if (not self._mesh_by_size
+                or bucket_size(n_nodes) == self._size_rule_bucket):
+            return
+        want = self._size_rule_mesh(n_nodes)
+        have = self._configured_mesh
+        if (want.devices.size if want is not None else 1) == (
+                have.devices.size if have is not None else 1):
+            return
+        logger.info("mesh size rule: %d nodes now run on %d device(s)",
+                    n_nodes, want.devices.size if want else 1)
+        self._configured_mesh = want
+        self._lost_device_ids = set()
+        self._submesh_cache = {}
+        # a resize re-keys the step cache legitimately, as a ladder
+        # transition does: the steady-state compile guard drops
+        self._steady_state = False
+        self._apply_degraded_level()
+
+    def _shards(self) -> int:
+        """Chips the node axis is placed over now (1: single device)."""
+        return self.mesh.devices.size if self.mesh is not None else 1
+
+    def _dispatch_shards(self) -> str:
+        """``_shards`` for a ``kernel`` span's ``shards=``, counted in
+        koord_scheduler_mesh_dispatches_total."""
+        n = str(self._shards())
+        scheduler_metrics.MESH_DISPATCHES.inc(shards=n)
+        return n
+
     def note_warmup_complete(self, stats: Dict) -> None:
         """The warm-up ladder finished: arm the steady-state compile
         guard (koordlint rule 20's runtime half) — from here on, a
@@ -860,7 +929,7 @@ class Scheduler:
         legitimate cold compiles would make the metric unusable)."""
         scheduler_metrics.WARMUP_COMPLETE.set(1.0)
         self._steady_state = (
-            stats.get("warmed", 0) + stats.get("built", 0)) > 0
+            stats.get("warmed", 0)) > 0
         logger.info(
             "warm-up ladder complete: %d/%d rungs warmed in %.2fs "
             "(%d skipped, %d failed, %d invalidated)",
@@ -2417,6 +2486,7 @@ class Scheduler:
             if transform_score:
                 fc = self.extender.transform_before_score(fc, ctx)
             fc, active = reduce_to_active_axes(fc)
+            self._resize_gate(fc.base.allocatable.shape[0])
             # keep the packed batch for end-of-cycle unschedulability
             # diagnosis (scheduler/diagnose.py reads the same arrays the
             # kernel consumed); a retry pass overwrites this with the
@@ -2452,6 +2522,7 @@ class Scheduler:
             delta["bytes_scattered"] + delta["bytes_put"])
         span.attributes["fields_put"] = str(delta["put"])
         span.attributes["fields_scattered"] = str(delta["scattered"])
+        span.attributes["shards"] = str(self._shards())
 
     def _readback_sync(self, n_shape: Tuple[int, int], *arrays,
                        path: str = "serial"):
@@ -2579,7 +2650,8 @@ class Scheduler:
         )
         with self.tracer.span(
                 "kernel",
-                compiled="1" if self._last_step_compiled else "0") as ksp:
+                compiled="1" if self._last_step_compiled else "0",
+                shards=self._dispatch_shards()) as ksp:
             from koordinator_tpu.scheduler.sidecar import (
                 schedule_batch_or_fallback,
             )
@@ -2633,6 +2705,7 @@ class Scheduler:
                         "kernel",
                         compiled="1" if self._last_step_compiled
                         else "0",
+                        shards=self._dispatch_shards(),
                         decision_id=win.decision_id) as ksp:
                     fc = fc_host
                     if self.device_snapshot is not None:
@@ -2753,7 +2826,8 @@ class Scheduler:
         exception (flight recorder ``cycle_exception`` trigger).
         ``_last_batch`` keeps the host arrays, so unschedulability
         diagnosis runs through the legacy host recompute unchanged."""
-        with self.tracer.span("kernel", host_fallback="1") as ksp:
+        with self.tracer.span("kernel", host_fallback="1",
+                              shards="0") as ksp:
             chosen = host_fallback_schedule(fc_host, pods,
                                             len(nodes.names))
         result.kernel_seconds += ksp.duration_seconds
@@ -3061,6 +3135,7 @@ class Scheduler:
                         "kernel",
                         compiled="1" if self._last_step_compiled else "0",
                         waves=str(k_waves),
+                        shards=self._dispatch_shards(),
                         decision_id=win.decision_id) as ksp:
                     fc = fc_host
                     up_fields = side_fields
@@ -3531,6 +3606,7 @@ class Scheduler:
                         "kernel",
                         compiled="1" if self._last_step_compiled else "0",
                         waves=str(k_waves), overlap="1",
+                        shards=self._dispatch_shards(),
                         decision_id=win.decision_id):
                     fc = fc_host
                     up_fields = side_fields

@@ -177,6 +177,11 @@ MESH_DEVICES = REGISTRY.gauge(
     "koord_scheduler_mesh_devices",
     "Devices in the production dispatch mesh (0 = single-device)",
 )
+MESH_DISPATCHES = REGISTRY.counter(
+    "koord_scheduler_mesh_dispatches_total",
+    "Device dispatches (kernel spans), labeled by the shards they ran "
+    "over (1 = single-device)",
+)
 MESH_SHARD_READBACK_BYTES = REGISTRY.gauge(
     "koord_scheduler_mesh_readback_bytes",
     "Bytes of the last kernel readback held per mesh shard, "

@@ -816,8 +816,6 @@ class DeviceSnapshot:
 
     def _scatter(self, dev, idx: np.ndarray, rows: np.ndarray,
                  sharding=None):
-        import jax
-
         if idx.size == 0:
             # guard the empty dirty-row set: idx[-1] below indexes a
             # zero-length array (IndexError), and a zero-row scatter is a
@@ -831,11 +829,29 @@ class DeviceSnapshot:
         rows_p[: idx.size] = rows
         with self._lock:
             donate = self._in_flight == 0
+        fn = self._scatter_fn(dev.shape, dev.dtype, pad, donate, sharding)
+        if not donate:
+            self.stats["scattered_safe"] += 1
+        if sharding is not None:
+            from koordinator_tpu.parallel.mesh import put_on_mesh
+
+            rep = self._sharding(False)
+            idx_p = put_on_mesh(idx_p, rep)
+            rows_p = put_on_mesh(rows_p, rep)
+        return fn(dev, idx_p, rows_p)
+
+    def _scatter_fn(self, shape, dtype, pad: int, donate: bool, sharding):
+        """The jitted row-delta program for a field of ``shape``/``dtype``
+        at pad bucket ``pad``. Under a mesh a new program is recorded in
+        the warm-up index, so the next process's ladder compiles it
+        before its first cycle (``warm_scatter``)."""
+        import jax
+
         # the sharding itself (hashable) keys the cache: node-sharded and
         # replicated fields of equal shape/dtype must NOT share a jitted
         # fn, or the pinned out_shardings of whichever compiled first
         # would silently reshard the other
-        key = (dev.shape, str(dev.dtype), pad, donate, sharding)
+        key = (tuple(shape), str(dtype), pad, donate, sharding)
         fn = self._scatter_cache.get(key)
         if fn is None:
             # under a mesh the output sharding is pinned to the input's
@@ -846,15 +862,52 @@ class DeviceSnapshot:
                          donate_argnums=(0,) if donate else (),
                          out_shardings=sharding)
             self._scatter_cache[key] = fn
-        if not donate:
-            self.stats["scattered_safe"] += 1
-        if sharding is not None:
-            from koordinator_tpu.parallel.mesh import put_on_mesh
+            if self.mesh is not None:
+                from koordinator_tpu.scheduler.warmup import (
+                    record_step_compile,
+                )
 
-            rep = self._sharding(False)
-            idx_p = put_on_mesh(idx_p, rep)
-            rows_p = put_on_mesh(rows_p, rep)
-        return fn(dev, idx_p, rows_p)
+                record_step_compile("scatter", {
+                    "shape": [int(d) for d in shape], "dtype": str(dtype),
+                    "pad": int(pad), "donate": bool(donate),
+                    "node_axis": sharding == self._sharding(True),
+                    "mesh_tag": [int(d.id) for d in self.mesh.devices.flat],
+                }, ())
+        return fn
+
+    def warm_scatter(self, shape, dtype, pad: int, donate: bool,
+                     node_axis: bool) -> None:
+        """Compile one recorded row-delta program under the mesh (the
+        warm-up ladder's scatter rung): run it once on zeros placed as
+        the upload places such a field, so a cycle's first scatter of
+        that bucket finds it compiled."""
+        import jax
+
+        from koordinator_tpu.parallel.mesh import put_on_mesh
+
+        sharding = self._sharding(node_axis)
+        rep = self._sharding(False)
+        fn = self._scatter_fn(shape, dtype, pad, donate, sharding)
+        out = fn(put_on_mesh(np.zeros(shape, dtype), sharding),
+                 put_on_mesh(np.zeros(pad, np.int32), rep),
+                 put_on_mesh(np.zeros((pad,) + tuple(shape[1:]), dtype),
+                             rep))
+        # start-up ladder, not a dispatch window
+        # koordlint: disable=naked-device-sync-without-deadline
+        jax.block_until_ready(out)
+
+    def place(self, fc):
+        """``fc``'s host arrays on the mesh as ``upload`` places them,
+        without touching the mirror (the warm-up ladder's operands)."""
+        from koordinator_tpu.parallel.mesh import put_on_mesh
+
+        def put(name, arr):
+            return put_on_mesh(arr, self._sharding(name in self._node_fields))
+
+        base = type(fc.base)(**{k: put(k, v)
+                                for k, v in fc.base._asdict().items()})
+        return type(fc)(base=base, **{k: put(k, v) for k, v in
+                                      fc._asdict().items() if k != "base"})
 
     def _one(self, name: str, new) -> object:
         """One field through the reuse/scatter/put machinery, with

@@ -397,7 +397,10 @@ class WarmupRunner:
     Mesh discipline: a rung recorded under a mesh device-id tuple only
     replays when the scheduler's CURRENT placement matches (koordguard:
     two same-size submeshes never share a step); mismatches count as
-    ``skipped``. Every rung runs inside try/except — a corrupted entry
+    ``skipped``. A mesh step rung runs on zeros placed as the cycle
+    places its operands, and the mesh DeviceSnapshot's row-delta
+    programs replay as ``scatter`` rungs, one per field shape and pad
+    bucket a process met. Every rung runs inside try/except — a corrupted entry
     or a failed zero-call counts as ``failed`` and warm-up continues."""
 
     def __init__(self, scheduler, background: bool = False) -> None:
@@ -408,7 +411,7 @@ class WarmupRunner:
         # own tracer: the background ladder must not interleave spans
         # into the cycle thread's ring mid-cycle
         self.tracer = Tracer()
-        self.stats = {"rungs": 0, "warmed": 0, "built": 0, "skipped": 0,
+        self.stats = {"rungs": 0, "warmed": 0, "skipped": 0,
                       "failed": 0, "invalidated": 0, "seconds": 0.0,
                       "complete": False}
         self._thread: Optional[threading.Thread] = None
@@ -450,17 +453,58 @@ class WarmupRunner:
                 explain=explain, sides_tag=tuple(meta["sides_tag"]))
         else:
             return "skipped"
+        args = [zeros_from_spec(s) for s in entry["args"]]
         if mesh_rung:
-            # mesh rungs are BUILD-ONLY: a zero-call with host operands
-            # commits different input shardings than the production
-            # upload path, which hashes to a DIFFERENT program — the
-            # zero-call would compile fresh instead of hitting the disk
-            # entry the real dispatch wrote. Building through the keyed
-            # chokepoint still pre-populates the in-memory step cache;
-            # the first real dispatch re-traces the recorded HLO and
-            # ITS XLA compile is the disk hit.
-            return "built"
-        self._zero_call(step, entry)
+            # a mesh rung runs on operands placed as the cycle places
+            # them (the DeviceSnapshot's shardings, the chain's wave-0
+            # carry): host operands would commit other input shardings
+            # and compile a program no dispatch runs
+            args = self._place_mesh_args(kind, meta, args)
+        self._zero_call(step, args)
+        return "warmed"
+
+    def _place_mesh_args(self, kind: str, meta: dict, args: list):
+        """A mesh rung's zero operands on the scheduler's mesh: the
+        packed batch through ``DeviceSnapshot.place``, the wave side
+        inputs under ``wave_side_shardings``, the chain's carry rebuilt
+        from them as a cycle builds it."""
+        import jax
+
+        from koordinator_tpu.parallel.full_chain_mesh import (
+            wave_side_shardings,
+        )
+        from koordinator_tpu.parallel.mesh import put_on_mesh
+
+        sched = self.scheduler
+        snap = sched.device_snapshot  # a mesh scheduler always holds one
+        args[0] = snap.place(args[0])
+        if kind == "serial":
+            return args
+        sides = args[2] if kind == "chain" else args[1]
+        sides = jax.tree.map(
+            put_on_mesh, sides,
+            wave_side_shardings(snap.mesh, prod=sides.prod is not None,
+                                claims=sides.claims is not None,
+                                res=sides.res is not None))
+        if kind == "chain":
+            args[1] = sched._initial_chain_carry(args[0], sides,
+                                                 meta.get("explain"))
+            args[2] = sides
+        else:
+            args[1] = sides
+        return args
+
+    def _replay_scatter_rung(self, entry: dict):
+        """A DeviceSnapshot row-delta program recorded under this mesh:
+        compiled into the scheduler's mirror before its first cycle."""
+        sched = self.scheduler
+        meta = entry["meta"]
+        snap = sched.device_snapshot
+        if (snap is None or snap.mesh is None
+                or tuple(meta.get("mesh_tag", ())) != sched._mesh_tag()):
+            return "skipped"
+        snap.warm_scatter(tuple(meta["shape"]), np.dtype(meta["dtype"]),
+                          meta["pad"], meta["donate"], meta["node_axis"])
         return "warmed"
 
     def _replay_standalone_rung(self, entry: dict):
@@ -480,13 +524,12 @@ class WarmupRunner:
             step = build_colo_step(meta["policies"][0], meta["policies"][1])
         else:
             return "skipped"
-        self._zero_call(step, entry)
+        self._zero_call(step, [zeros_from_spec(s) for s in entry["args"]])
         return "warmed"
 
-    def _zero_call(self, step, entry: dict) -> None:
+    def _zero_call(self, step, args) -> None:
         import jax
 
-        args = tuple(zeros_from_spec(s) for s in entry["args"])
         t0 = time.perf_counter()
         out = step(*args)
         # startup-time ladder, not a dispatch window: a hung device
@@ -526,6 +569,8 @@ class WarmupRunner:
                     try:
                         if kind in ("serial", "fused", "chain"):
                             outcome = self._replay_scheduler_rung(entry)
+                        elif kind == "scatter":
+                            outcome = self._replay_scatter_rung(entry)
                         else:
                             outcome = self._replay_standalone_rung(entry)
                     except Exception:

@@ -6,7 +6,10 @@ chip allows is refused here, at no chip time. These tests pin that the
 Pallas selectors never route a shape to a kernel the v5e compiler refuses
 (ops/pallas_common.VMEM_LIMIT_BYTES, the estimate_vmem_bytes bounds), that
 BASELINE config 4 (10,240 x 5,120 padded) stays on Pallas, and that the
-LoadAware Pallas step and the chained wave step compile at that shape.
+LoadAware Pallas step and the chained wave step compile at that shape,
+and that the sharded steps the size rule picks for the mesh-50k cell
+(50,000 nodes, 57,344 padded, over a 2x2 host) compile with their
+cross-chip collectives.
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu at a time, and the driver's workers import
@@ -24,11 +27,10 @@ R_FULL, K_FULL = 14, 8  # synth_full_cluster without axis reduction
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -39,9 +41,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -178,3 +187,78 @@ def test_chained_wave_step_compiles_at_10k_x_5k(template, one_chip):
     step = build_chained_wave_step(LoadAwareArgs(), ng, ngroups, jit=False,
                                    active_axes=tuple(range(R)))
     jax.jit(step).lower(fc_s, carry, sides).compile()
+
+
+@pytest.fixture(scope="module")
+def mesh_50k(v5e_2x2, template):
+    """The template at mesh-50k's padded shape (P=256, N=57,344) placed
+    as the scheduler's DeviceSnapshot places it over a described 2x2
+    host: node-axis fields flat over the four chips, the rest
+    replicated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from koordinator_tpu.parallel.mesh import make_mesh
+    from koordinator_tpu.scheduler.snapshot_cache import _mesh_node_fields
+
+    mesh = make_mesh(list(v5e_2x2.devices))
+    node_fields = _mesh_node_fields()
+    node = NamedSharding(mesh, P(("pods", "nodes")))
+    rep = NamedSharding(mesh, P())
+    fc, ng, ngroups = template
+    size = {128: 256, 64: 57_344}
+
+    def put(name, x):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(
+            tuple(size.get(d, d) for d in x.shape), x.dtype,
+            sharding=node if name in node_fields else rep)
+
+    base = type(fc.base)(**{k: put(k, v)
+                            for k, v in fc.base._asdict().items()})
+    fc_s = type(fc)(base=base, **{k: put(k, v) for k, v in
+                                  fc._asdict().items() if k != "base"})
+    return mesh, fc_s, ng, ngroups, node
+
+
+def test_sharded_single_round_step_compiles_at_mesh_50k(mesh_50k):
+    from koordinator_tpu.ops.loadaware import LoadAwareArgs
+    from koordinator_tpu.ops.pallas_full_chain import estimate_vmem_bytes
+    from koordinator_tpu.parallel.full_chain_mesh import (
+        build_sharded_full_chain_step,
+    )
+
+    mesh, fc_s, ng, ngroups, _node = mesh_50k
+    assert estimate_vmem_bytes(57_344, 3, 2, 1_000) > _v5e_budget()
+    step = build_sharded_full_chain_step(LoadAwareArgs(), ng, ngroups, mesh,
+                                         active_axes=(0, 1, 2))
+    text = step.lower(fc_s).compile().as_text()
+    assert "all-reduce" in text
+
+
+def test_sharded_chained_wave_step_compiles_at_mesh_50k(mesh_50k):
+    import jax
+
+    from koordinator_tpu.models.fused_waves import (
+        initial_wave_carry,
+        plain_sides,
+    )
+    from koordinator_tpu.ops.loadaware import LoadAwareArgs
+    from koordinator_tpu.parallel.full_chain_mesh import (
+        build_sharded_chained_wave_step,
+        wave_carry_shardings,
+    )
+
+    mesh, fc_s, ng, ngroups, node = mesh_50k
+    R = fc_s.base.fit_requests.shape[1]
+    nr = jax.ShapeDtypeStruct((57_344, R), np.float32, sharding=node)
+    sides = plain_sides(nr, nr)
+    carry = tuple(
+        None if sh is None else jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                     sharding=sh)
+        for x, sh in zip(jax.eval_shape(initial_wave_carry, fc_s, sides),
+                         wave_carry_shardings(mesh)))
+    step = build_sharded_chained_wave_step(LoadAwareArgs(), ng, ngroups,
+                                           mesh, active_axes=(0, 1, 2))
+    text = step.lower(fc_s, carry, sides).compile().as_text()
+    assert "all-reduce" in text

@@ -124,7 +124,7 @@ class TestWarmupLadder:
         entries = wu.CompileCacheIndex(cache_dir).load()
         assert entries, "dispatch compiles must record rungs"
         assert {e["kind"] for e in entries.values()} <= {
-            "serial", "fused", "chain", "rebalance", "colo"}
+            "serial", "fused", "chain", "rebalance", "colo", "scatter"}
 
         # the "restarted" scheduler: same store world, sync warm-up
         state2, store2 = _world()
@@ -147,6 +147,42 @@ class TestWarmupLadder:
         assert scheduler_metrics.COMPILE_CACHE_MISSES.get() == m0
         assert flagged == []
 
+    @pytest.mark.parametrize("waves", [1, 4])
+    def test_mesh_rungs_and_scatters_warm_on_the_mesh(
+            self, cache_dir, cpu_devices, waves):
+        """A restarted mesh scheduler runs its step rungs on operands
+        placed as a cycle places them, and compiles the row-delta
+        programs its predecessor met as ``scatter`` rungs: the same
+        rounds then compile neither a step nor a scatter."""
+        state, store = _world(seed=31)
+        sched = Scheduler(store, waves=waves, explain="off", warmup="off",
+                          mesh=4)
+        _run_rounds(sched, store, state.now)
+        tag = [int(d.id) for d in sched.mesh.devices.flat]
+        kinds = {e["kind"] for e in
+                 wu.CompileCacheIndex(cache_dir).load().values()
+                 if e["meta"].get("mesh_tag") == tag}
+        assert "scatter" in kinds and kinds & {"serial", "fused", "chain"}
+
+        state2, store2 = _world(seed=31)
+        sched2 = Scheduler(store2, waves=waves, explain="off",
+                           warmup="sync", mesh=4)
+        stats = sched2.warmup.stats
+        assert stats["warmed"] > 0
+        assert stats["failed"] == 0
+        scatters = len(sched2.device_snapshot._scatter_cache)
+        assert scatters > 0
+        # each warmed step holds the executable of the cycle's own
+        # operand shardings: the rounds add none
+        steps = dict(sched2._step_cache)
+        held = {k: steps[k]._cache_size() for k in steps}
+        assert held and all(n >= 1 for n in held.values())
+        m0 = scheduler_metrics.COMPILE_CACHE_MISSES.get()
+        _run_rounds(sched2, store2, state2.now)
+        assert scheduler_metrics.COMPILE_CACHE_MISSES.get() == m0
+        assert len(sched2.device_snapshot._scatter_cache) == scatters
+        assert {k: steps[k]._cache_size() for k in steps} == held
+
     def test_fingerprint_bump_invalidates_and_recompiles(
             self, cache_dir, monkeypatch):
         state, store = _world(seed=9)
@@ -158,7 +194,7 @@ class TestWarmupLadder:
         state2, store2 = _world(seed=9)
         sched2 = Scheduler(store2, waves=1, explain="off", warmup="sync")
         stats = sched2.warmup.stats
-        assert stats["warmed"] == 0 and stats["built"] == 0
+        assert stats["warmed"] == 0
         assert stats["invalidated"] == stats["rungs"] > 0
         # ...and the on-demand compile path still works (recompiled)
         m0 = scheduler_metrics.COMPILE_CACHE_MISSES.get()
@@ -198,7 +234,7 @@ class TestWarmupLadder:
         state, store = _world(seed=21)
         sched = Scheduler(store, waves=1, explain="off", warmup="off")
         sched.note_warmup_complete(
-            {"warmed": 2, "built": 0, "rungs": 2, "seconds": 0.1,
+            {"warmed": 2, "rungs": 2, "seconds": 0.1,
              "skipped": 0, "failed": 0, "invalidated": 0})
         assert sched._steady_state is True
         sched._on_ladder_transition(
@@ -213,7 +249,7 @@ class TestWarmupLadder:
         state, store = _world(seed=25)
         sched = Scheduler(store, waves=1, explain="off", warmup="off")
         sched.note_warmup_complete(
-            {"warmed": 0, "built": 0, "rungs": 0, "seconds": 0.0,
+            {"warmed": 0, "rungs": 0, "seconds": 0.0,
              "skipped": 0, "failed": 0, "invalidated": 0})
         assert sched._steady_state is False
         flagged = []
