@@ -646,6 +646,37 @@ def full_chain_vmem_bytes(fc: FullChainInputs) -> int:
         fc.img_scores.shape[1])
 
 
+def pallas_full_chain_fits(fc: FullChainInputs, vmem_budget_bytes,
+                           volumes: bool, explain=None) -> bool:
+    """Whether the Pallas full-chain kernel serves ``fc``: the one rule
+    the single-round selector (build_best_full_chain_step) and the fused
+    wave body's search pass (models/fused_waves.wave_search_backend) both
+    read. Shapes and static flags only, so it folds at trace time.
+
+    ``vmem_budget_bytes`` is the device kind's scoped-VMEM budget
+    (ops/pallas_common.default_vmem_budget), which only the TPU backend
+    has: None keeps the XLA step. The kernel's VMEM estimate must fit it
+    and its SMEM estimate the Mosaic limit; ``volumes`` (any pod of the
+    batch mounts new volumes) sizes the per-pod volume rows, which the
+    volume-less variant compiles out. koordexplain attribution runs on
+    the XLA step alone."""
+    from koordinator_tpu.ops.pallas_full_chain import (
+        SMEM_BUDGET_BYTES,
+        estimate_smem_bytes,
+    )
+
+    if explain is not None or vmem_budget_bytes is None:
+        return False
+    if full_chain_vmem_bytes(fc) > vmem_budget_bytes:
+        return False
+    P = fc.base.fit_requests.shape[0]
+    T = fc.aff_dom.shape[1]
+    VG = fc.vol_needed.shape[1]
+    S2 = fc.ppref_w.shape[0] if T else 0
+    return (estimate_smem_bytes(P, VG if volumes else 0, T, S2)
+            <= SMEM_BUDGET_BYTES)
+
+
 def node_axis_vmem_bytes(n_nodes: int) -> int:
     """``full_chain_vmem_bytes`` at ``n_nodes``' bucket with the fewest
     axes a pack keeps (scheduler/snapshot.reduce_to_active_axes: the
@@ -724,9 +755,7 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         return xla_step
     from koordinator_tpu.ops import pallas_common as pc
     from koordinator_tpu.ops.pallas_full_chain import (
-        SMEM_BUDGET_BYTES,
         build_pallas_full_chain_step,
-        estimate_smem_bytes,
     )
 
     budget = (pc.default_vmem_budget() if vmem_budget_bytes is None
@@ -743,14 +772,11 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
         return pallas_steps[enable_volumes]
 
     def step(fc: FullChainInputs):
-        P = fc.base.fit_requests.shape[0]
-        T = fc.aff_dom.shape[1]
-        VG = fc.vol_needed.shape[1]
-        S2 = fc.ppref_w.shape[0] if T else 0
-        # VMEM budget first: a batch bound for the XLA step anyway must
-        # not pay the vol-flag resolution (which can cost a D2H readback
-        # for fresh device-resident arrays)
-        if full_chain_vmem_bytes(fc) > budget:
+        # the volume-less variant first: a batch no variant admits (the
+        # VMEM budget, or SMEM without volume rows) must not pay the
+        # vol-flag resolution (which can cost a D2H readback for fresh
+        # device-resident arrays)
+        if not pallas_full_chain_fits(fc, budget, volumes=False):
             step.last_backend = "xla"
             return xla_step(fc)
         # the snapshot builder hands HOST (numpy) arrays, so this check
@@ -780,8 +806,7 @@ def build_best_full_chain_step(args: LoadAwareArgs, num_gangs: int,
                 cache[id(vn)] = (weakref.ref(vn), vol)
         else:
             vol = True
-        if (estimate_smem_bytes(P, VG if vol else 0, T, S2)
-                <= SMEM_BUDGET_BYTES):
+        if pallas_full_chain_fits(fc, budget, volumes=vol):
             step.last_backend = "pallas"
             return _pallas(vol)(fc)
         step.last_backend = "xla"

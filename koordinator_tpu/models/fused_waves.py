@@ -19,10 +19,12 @@ scheduling round serial cycle w would run:
        reservations that became Available in an EARLIER wave of this
        dispatch, consuming carried remainders — so a migration-created
        Reservation is consumable by wave 2 of the same dispatch);
-    1. evaluation pass — the serial full-chain round (the same
-       ``make_pod_evaluator`` + ``commit_pod_state`` the single-round
-       kernel traces, models/full_chain.py) over the still-pending pods,
-       producing tentative bindings with in-round state feedback;
+    1. evaluation pass — the serial full-chain round over the
+       still-pending pods, producing tentative bindings with in-round
+       state feedback: the Pallas full-chain kernel where the single-
+       round selector's rule admits the batch (``wave_search_backend``),
+       else the same ``make_pod_evaluator`` + ``commit_pod_state`` the
+       single-round XLA step traces (models/full_chain.py);
     2. gang Permit against the CARRIED assumed counters;
     3. kept-only replay pass — the next wave's state is rebuilt from the
        wave-start state by committing ONLY the pods that survived Permit,
@@ -109,6 +111,7 @@ from koordinator_tpu.models.full_chain import (
     commit_pod_state,
     explain_stage_counts,
     make_pod_evaluator,
+    pallas_full_chain_fits,
     resolve_balance_idx,
     resolve_weight_idx,
 )
@@ -235,6 +238,42 @@ def plain_sides(la_est, la_adj) -> WaveSideInputs:
     return WaveSideInputs(la_est=la_est, la_adj=la_adj)
 
 
+def wave_search_backend(fc: FullChainInputs, vmem_budget_bytes,
+                        volumes: bool, explain=None,
+                        claims: bool = False) -> str:
+    """The kernel of the wave body's search pass (pass 1): "pallas" where
+    the single-round selector's rule (pallas_full_chain_fits) admits the
+    batch, else "xla" (the ``fori_loop`` evaluator). A hot-claims batch
+    keeps the XLA pass: each wave rewrites its vol_needed to a [P, N]
+    view (effective_vol_needed), a volume row per node that the kernel's
+    SMEM cannot hold at a deployed size."""
+    if not claims and pallas_full_chain_fits(fc, vmem_budget_bytes,
+                                             volumes, explain):
+        return "pallas"
+    return "xla"
+
+
+def _pallas_search_pass(args: LoadAwareArgs, active_axes,
+                        fc: FullChainInputs, vmem_budget_bytes,
+                        volumes: bool, explain, claims: bool,
+                        interpret: bool):
+    """Pass 1 on the Pallas kernel, ``fc -> chosen[P]`` before Permit, or
+    None where ``wave_search_backend`` keeps the XLA pass."""
+    if wave_search_backend(fc, vmem_budget_bytes, volumes, explain,
+                           claims) != "pallas":
+        return None
+    from koordinator_tpu.ops.pallas_full_chain import (
+        pallas_full_chain_search,
+    )
+
+    def search(fc_start: FullChainInputs):
+        return pallas_full_chain_search(
+            fc_start, args, active_axes=active_axes,
+            enable_volumes=volumes, interpret=interpret)[0]
+
+    return search
+
+
 def _check_wave_args(args: LoadAwareArgs, sides_prod: bool) -> None:
     if args.score_according_prod_usage != sides_prod:
         # the carry's est_sum_prod slot presence must equal prod_mode or
@@ -282,7 +321,8 @@ def _carry_fc_variants(fc: FullChainInputs, sides: WaveSideInputs):
 
 def _make_wave_body(fc: FullChainInputs, sides: WaveSideInputs, n_real,
                     weight_idx, bal_idx, num_gangs: int, num_groups: int,
-                    explain, prod_mode: bool, score_passes=()):
+                    explain, prod_mode: bool, score_passes=(),
+                    search=None):
     """The ONE wave body both dispatch shapes trace.
 
     ``carry`` layout: WAVE_STATE_FIELDS (NUM_WAVE_STATE slots, None where
@@ -293,6 +333,9 @@ def _make_wave_body(fc: FullChainInputs, sides: WaveSideInputs, n_real,
     fused step and the chained step cannot drift — byte parity between
     them is by construction of the trace, and pipeline_parity gates it
     empirically.
+
+    ``search`` (``_pallas_search_pass``) runs pass 1 on the Pallas
+    kernel; None runs it on the ``fori_loop`` evaluator.
     """
     inputs = fc.base
     P, R = inputs.fit_requests.shape
@@ -451,54 +494,66 @@ def _make_wave_body(fc: FullChainInputs, sides: WaveSideInputs, n_real,
             ex_counts = jax.lax.dynamic_update_slice(
                 ex_counts, counts_w[None], (w, 0, 0))
 
-        # ---- pass 1: the serial round (identical tracing to
-        # build_full_chain_step's body — decisions are by construction
-        # what serial cycle w's kernel would decide)
-        def body(i, state):
-            if explain_full:
-                chain_state, wterms, chosen = (state[:-2], state[-2],
-                                               state[-1])
-                (found, best, zone_at_best, _adm, score, _b, best_v,
-                 la_row, numa_row, pref_row) = evaluate(i, *chain_state)
-                runner = jnp.maximum(jnp.max(jnp.where(
-                    jnp.arange(N, dtype=jnp.int32) == best,
-                    -jnp.inf, score)), -1.0)
-                wterms = wterms.at[i].set(jnp.stack([
-                    la_row[best], numa_row[best], pref_row[best],
-                    best_v, runner]))
-            else:
-                chain_state, chosen = state[:-1], state[-1]
-                found, best, zone_at_best, _adm, _s, _b, _mv = evaluate(
-                    i, *chain_state)
-            chain_state = commit_pod_state(
-                fc_w, prod_mode, chain_state, i, found, best,
-                zone_at_best)
-            chosen = chosen.at[i].set(
-                jnp.where(found, best.astype(jnp.int32), -1))
-            if explain_full:
-                return chain_state + (wterms, chosen)
-            return chain_state + (chosen,)
+        if search is not None:
+            # ---- pass 1 on the Pallas full-chain kernel: the same
+            # serial round (bit-identical bindings, tests/
+            # test_wave_pallas.py) over the wave-start carried state
+            chosen = search(fc_w._replace(
+                base=fc_w.base._replace(requested=requested),
+                numa_free=numa_free, bind_free=bind_free,
+                quota_used=quota_used, aff_count=aff_count,
+                anti_cover=anti_cover, aff_exists=aff_exists,
+                port_used=port_used, vol_free=vol_free))
+            wave_terms = None
+        else:
+            # ---- pass 1: the serial round (identical tracing to
+            # build_full_chain_step's body — decisions are by construction
+            # what serial cycle w's kernel would decide)
+            def body(i, state):
+                if explain_full:
+                    chain_state, wterms, chosen = (state[:-2], state[-2],
+                                                   state[-1])
+                    (found, best, zone_at_best, _adm, score, _b, best_v,
+                     la_row, numa_row, pref_row) = evaluate(i, *chain_state)
+                    runner = jnp.maximum(jnp.max(jnp.where(
+                        jnp.arange(N, dtype=jnp.int32) == best,
+                        -jnp.inf, score)), -1.0)
+                    wterms = wterms.at[i].set(jnp.stack([
+                        la_row[best], numa_row[best], pref_row[best],
+                        best_v, runner]))
+                else:
+                    chain_state, chosen = state[:-1], state[-1]
+                    found, best, zone_at_best, _adm, _s, _b, _mv = evaluate(
+                        i, *chain_state)
+                chain_state = commit_pod_state(
+                    fc_w, prod_mode, chain_state, i, found, best,
+                    zone_at_best)
+                chosen = chosen.at[i].set(
+                    jnp.where(found, best.astype(jnp.int32), -1))
+                if explain_full:
+                    return chain_state + (wterms, chosen)
+                return chain_state + (chosen,)
 
-        init = (
-            requested,
-            jnp.zeros((N, R), jnp.float32),
-            jnp.zeros((N, R), jnp.float32),
-            numa_free,
-            bind_free,
-            quota_used,
-            aff_count,
-            anti_cover,
-            aff_exists,
-            port_used,
-            vol_free,
-        )
-        if explain_full:
-            init = init + (
-                jnp.zeros((P, len(EXPLAIN_TERMS)), jnp.float32),)
-        init = init + (jnp.full(P, -1, jnp.int32),)
-        pass1 = jax.lax.fori_loop(0, P, body, init)
-        chosen = pass1[-1]
-        wave_terms = pass1[-2] if explain_full else None
+            init = (
+                requested,
+                jnp.zeros((N, R), jnp.float32),
+                jnp.zeros((N, R), jnp.float32),
+                numa_free,
+                bind_free,
+                quota_used,
+                aff_count,
+                anti_cover,
+                aff_exists,
+                port_used,
+                vol_free,
+            )
+            if explain_full:
+                init = init + (
+                    jnp.zeros((P, len(EXPLAIN_TERMS)), jnp.float32),)
+            init = init + (jnp.full(P, -1, jnp.int32),)
+            pass1 = jax.lax.fori_loop(0, P, body, init)
+            chosen = pass1[-1]
+            wave_terms = pass1[-2] if explain_full else None
 
         # ---- Permit barrier against the CARRIED assumed counters
         with jax.named_scope("select"):
@@ -677,7 +732,9 @@ def build_fused_wave_step(args: LoadAwareArgs, num_gangs: int,
                           num_groups: int, waves: int, jit: bool = True,
                           active_axes=None, explain=None,
                           prod: bool = False, claims: bool = False,
-                          res: bool = False, score_passes=()):
+                          res: bool = False, score_passes=(),
+                          vmem_budget_bytes=None, volumes: bool = True,
+                          interpret: bool = False):
     """(FullChainInputs, WaveSideInputs) -> FusedWaveOut.
 
     ``sides`` carries the LoadAware nonprod score-term split
@@ -696,6 +753,12 @@ def build_fused_wave_step(args: LoadAwareArgs, num_gangs: int,
     terms for each pod across waves (the wave that finally kept the pod
     wins the row). Decisions are untouched: attribution is extra carried
     outputs only.
+
+    ``vmem_budget_bytes`` (the device kind's, None off TPU) and
+    ``volumes`` (any pod mounts new volumes, resolved on the host) feed
+    ``wave_search_backend``, read at trace time: where it says "pallas",
+    every wave's search pass runs the Pallas full-chain kernel
+    (``interpret`` runs it in the Pallas interpreter, for tests).
     """
     if not 1 <= waves <= MAX_WAVES:
         raise ValueError(f"waves must be in [1, {MAX_WAVES}], got {waves}")
@@ -709,10 +772,12 @@ def build_fused_wave_step(args: LoadAwareArgs, num_gangs: int,
         inputs = fc.base
         P, _R = inputs.fit_requests.shape
 
-        wave_body = _make_wave_body(fc, sides, n_real, weight_idx,
-                                    bal_idx, num_gangs, num_groups,
-                                    explain, prod_mode,
-                                    score_passes=score_passes)
+        wave_body = _make_wave_body(
+            fc, sides, n_real, weight_idx, bal_idx, num_gangs, num_groups,
+            explain, prod_mode, score_passes=score_passes,
+            search=_pallas_search_pass(
+                args, active_axes, fc, vmem_budget_bytes, volumes, explain,
+                claims, interpret))
 
         def cond(carry):
             w, done = carry[-2], carry[-1]
@@ -763,7 +828,9 @@ def build_chained_wave_step(args: LoadAwareArgs, num_gangs: int,
                             num_groups: int, jit: bool = True,
                             active_axes=None, explain=None,
                             prod: bool = False, claims: bool = False,
-                            res: bool = False, score_passes=()):
+                            res: bool = False, score_passes=(),
+                            vmem_budget_bytes=None, volumes: bool = True,
+                            interpret: bool = False):
     """ONE wave per dispatch, carried state on device between dispatches.
 
     (FullChainInputs, carry, WaveSideInputs) -> (carry', WaveChainOut),
@@ -783,6 +850,9 @@ def build_chained_wave_step(args: LoadAwareArgs, num_gangs: int,
     driver stops consuming there (tracking the pending-transition flag
     host-side — a consumed allocate-once reservation arms one more
     wave, see scheduler/cycle.py).
+
+    ``vmem_budget_bytes``/``volumes``/``interpret``: the search pass's
+    kernel, as build_fused_wave_step takes them.
     """
     _check_wave_args(args, prod)
     weight_idx = resolve_weight_idx(args, active_axes)
@@ -793,10 +863,12 @@ def build_chained_wave_step(args: LoadAwareArgs, num_gangs: int,
     def _step_impl(fc: FullChainInputs, carry, sides: WaveSideInputs,
                    n_real):
         P = fc.base.fit_requests.shape[0]
-        wave_body = _make_wave_body(fc, sides, n_real, weight_idx,
-                                    bal_idx, num_gangs, num_groups,
-                                    explain, prod_mode,
-                                    score_passes=score_passes)
+        wave_body = _make_wave_body(
+            fc, sides, n_real, weight_idx, bal_idx, num_gangs, num_groups,
+            explain, prod_mode, score_passes=score_passes,
+            search=_pallas_search_pass(
+                args, active_axes, fc, vmem_budget_bytes, volumes, explain,
+                claims, interpret))
         full = tuple(carry[:NUM_WAVE_STATE]) + (
             jnp.full(P, -1, jnp.int32),
             jnp.full(P, -1, jnp.int32),

@@ -489,6 +489,238 @@ def _make_kernel(weights: np.ndarray, prod_mode: bool, N: int, R: int,
     return kernel
 
 
+def pallas_full_chain_search(fc: FullChainInputs, args: LoadAwareArgs,
+                             active_axes=None, enable_volumes: bool = True,
+                             interpret: bool = False
+                             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The kernel's serial round over ``fc``, before the gang Permit
+    post-pass: (chosen[P], requested[N, R], quota_used[G, R]), where
+    ``chosen`` is each pod's tentative node (-1 none) under in-batch state
+    feedback. Traceable and unjitted: build_pallas_full_chain_step applies
+    Permit against ``fc.gang_assumed``, the fused wave body
+    (models/fused_waves.py) against its carried counters.
+
+    enable_volumes=False compiles OUT the CSI volume-limit machinery (the
+    per-pod [N] compare/select/update) — valid only for batches where no
+    pod mounts volumes."""
+    weights = args.weight_vector()  # static float32, baked in
+    if active_axes is not None:
+        weights = weights[list(active_axes)]
+    prod_mode = args.score_according_prod_usage
+    inputs = fc.base
+    P, R = inputs.fit_requests.shape
+    N = inputs.allocatable.shape[0]
+    K = fc.numa_free.shape[1]
+    G = fc.quota_used.shape[0]
+    G_eff = max(G, 1)
+    reject_np, reject_prod = la_ops.loadaware_node_reject(
+        inputs.allocatable,
+        inputs.la_filter_usage,
+        inputs.la_has_filter_usage,
+        inputs.la_filter_thresholds,
+        inputs.la_prod_thresholds,
+        inputs.la_prod_pod_usage,
+        inputs.la_filter_skip,
+    )
+    gang_pod_ok = jnp.where(
+        fc.gang_id >= 0, fc.gang_valid[jnp.maximum(fc.gang_id, 0)], True
+    )
+    # ancestor closure computed traceably (inputs may be tracers under jit):
+    # closure[g, a] = 1 iff a appears in g's chain (-1 padding never matches)
+    if G:
+        anc = jnp.any(
+            fc.quota_ancestors[:, :, None]
+            == jnp.arange(G, dtype=fc.quota_ancestors.dtype)[None, None, :],
+            axis=1,
+        ).astype(jnp.float32)
+    else:
+        anc = jnp.zeros((1, 1), jnp.float32)
+
+    f32, row = pc.f32, pc.row
+    P_pad, pad_p = pc.pad_pods(P, POD_BLOCK)
+    spad = lambda x: jnp.pad(f32(x), pad_p)  # noqa: E731
+
+    def pods_t(x):  # [P, R] -> [R, P_pad]
+        return jnp.pad(f32(x), pad_p + [(0, 0)]).T
+
+    # numa [N, K, R] -> [K*R, N]
+    numa0 = jnp.transpose(f32(fc.numa_free), (1, 2, 0)).reshape(K * R, N)
+    # quota lane axis padded to >= 128: Mosaic cannot truncate the narrow
+    # bool vectors that comparisons on a (R, G<128) block would produce.
+    # Padding runtime with +inf keeps phantom groups from ever violating.
+    G_lane = max(128, -(-G_eff // 128) * 128)
+    if G:
+        qused0 = jnp.pad(f32(fc.quota_used).T, [(0, 0), (0, G_lane - G)])
+        qruntime = jnp.pad(f32(fc.quota_runtime).T,
+                           [(0, 0), (0, G_lane - G)],
+                           constant_values=jnp.inf)
+        qid = jnp.asarray(fc.quota_id, jnp.int32)
+    else:
+        qused0 = jnp.zeros((R, G_lane), jnp.float32)
+        qruntime = jnp.full((R, G_lane), jnp.inf, jnp.float32)
+        qid = jnp.full(P, -1, jnp.int32)
+    # pre-gather each pod's ancestor-closure row: [P_pad, G_lane] in HBM,
+    # streamed as [UNROLL, G_lane] blocks (quota-less pods hit row 0 of
+    # an all-zeros closure or carry has_quota == False, so the row is
+    # never applied)
+    qid_pad = jnp.pad(qid, pad_p, constant_values=-1)
+    anc = jnp.pad(anc, [(0, 0), (0, G_lane - anc.shape[1])])
+    anc_pod = jnp.take(anc, jnp.maximum(qid_pad, 0), axis=0)
+
+    # inter-pod affinity: per-pod term rows become [P] f32 bitmasks
+    # (exact: T <= 24 < 2^24), node state transposes to [T, N]
+    T = fc.aff_dom.shape[1]
+    T_eff = max(T, 1)
+    pow_t = jnp.asarray(
+        [float(1 << t) for t in range(T)], jnp.float32)
+    if T:
+        def bitmask(rows):  # [P, T] bool -> [P_pad] f32
+            return jnp.pad(
+                jnp.sum(f32(rows) * pow_t[None, :], axis=1), pad_p)
+
+        affreq_m = bitmask(fc.pod_aff_req)
+        antireq_m = bitmask(fc.pod_anti_req)
+        affmatch_m = bitmask(fc.pod_aff_match)
+        skew_i = jnp.asarray(fc.pod_spread_skew, jnp.int32)
+        skew0_m = bitmask((skew_i & 1) > 0)
+        skew1_m = bitmask((skew_i & 2) > 0)
+        skew2_m = bitmask((skew_i & 4) > 0)
+        affexists0 = f32(fc.aff_exists)
+        affdom0 = f32(fc.aff_dom).T
+        affcount0 = f32(fc.aff_count).T
+        anticover0 = f32(fc.anti_cover).T
+    else:
+        affreq_m = antireq_m = affmatch_m = jnp.zeros(P_pad, jnp.float32)
+        skew0_m = skew1_m = skew2_m = affreq_m
+        affexists0 = jnp.zeros(1, jnp.float32)
+        affdom0 = jnp.full((1, N), -1.0, jnp.float32)
+        affcount0 = jnp.zeros((1, N), jnp.float32)
+        anticover0 = jnp.zeros((1, N), jnp.float32)
+
+    # preference-less batches carry ZERO profile columns (snapshot emits
+    # true empties); the kernel skips the profile loops and the input
+    # slot gets one placeholder row
+    S = fc.pref_scores.shape[1]
+    S_eff = max(S, 1)
+    prefrows0 = (f32(fc.pref_scores).T if S
+                 else jnp.zeros((1, N), jnp.float32))
+    prefid_pad = jnp.pad(jnp.asarray(fc.pod_pref_id, jnp.int32), pad_p,
+                         constant_values=-1)
+    S2 = fc.ppref_w.shape[0] if T else 0  # zero rows == no profiles
+    pprefid_pad = jnp.pad(jnp.asarray(fc.pod_ppref_id, jnp.int32), pad_p,
+                          constant_values=-1)
+    pprefw0 = (f32(fc.ppref_w) if S2
+               else jnp.zeros((1, max(T, 1)), jnp.float32))
+
+    # NodePorts slots as per-pod f32 bitmasks (PT <= 16 < 2^24, exact),
+    # node state transposed [PT, N]; volume headroom as one [1, N] row;
+    # ImageLocality rows like the preference profiles
+    PT = fc.port_used.shape[1]
+    PT_eff = max(PT, 1)
+    if PT:
+        pow_s = jnp.asarray(
+            [float(1 << s) for s in range(PT)], jnp.float32)
+        portwants_m = jnp.pad(jnp.sum(
+            f32(fc.pod_port_wants) * pow_s[None, :], axis=1), pad_p)
+        portused0 = f32(fc.port_used).T
+    else:
+        portwants_m = jnp.zeros(P_pad, jnp.float32)
+        portused0 = jnp.zeros((1, N), jnp.float32)
+    VG = fc.vol_needed.shape[1]
+    if enable_volumes:
+        volneeded_pad = jnp.pad(
+            f32(fc.vol_needed), pad_p + [(0, 0)]).reshape(-1)
+    else:
+        # volume machinery compiled out: the kernel never reads the
+        # ref, so a 1-float placeholder keeps high-VG volume-less
+        # batches inside the SMEM budget
+        volneeded_pad = jnp.zeros(1, jnp.float32)
+    volfree0 = f32(fc.vol_free)[None, :]
+    volgrp0 = f32(fc.node_vol_group)[None, :]
+    SI = fc.img_scores.shape[1]
+    SI_eff = max(SI, 1)
+    imgrows0 = (f32(fc.img_scores).T if SI
+                else jnp.zeros((1, N), jnp.float32))
+    imgid_pad = jnp.pad(jnp.asarray(fc.pod_img_id, jnp.int32), pad_p,
+                        constant_values=-1)
+
+    kernel = _make_kernel(weights, prod_mode, N, R, K, G_eff, T, S, S2,
+                          PT, SI, VOL=enable_volumes, VG=VG,
+                          BAL=resolve_balance_idx(active_axes))
+    grid_inputs = (
+        spad(inputs.is_prod), spad(inputs.pod_valid),
+        spad(inputs.is_daemonset), spad(gang_pod_ok),
+        spad(fc.needs_numa), spad(fc.needs_bind),
+        spad(fc.full_pcpus), spad(fc.cores_needed),
+        jnp.pad(f32(fc.pod_taint_mask), pad_p, constant_values=1.0),
+        affreq_m, antireq_m, affmatch_m,
+        skew0_m, skew1_m, skew2_m, affexists0,
+        prefid_pad, pprefid_pad, pprefw0,
+        portwants_m, volneeded_pad, imgid_pad,
+        qid_pad,
+        pods_t(inputs.fit_requests), pods_t(fc.requests),
+        pods_t(inputs.estimated),
+        f32(inputs.allocatable).T, f32(inputs.requested).T,
+        f32(inputs.la_term_nonprod).T, f32(inputs.la_term_prod).T,
+        row(~reject_np), row(~reject_prod),
+        row(inputs.node_ok), row(inputs.la_score_valid),
+        row(fc.has_topology), row(fc.bind_free), row(fc.cpus_per_core),
+        jnp.asarray(fc.numa_policy, jnp.int32)[None, :],
+        jnp.exp2(f32(fc.node_taint_group))[None, :],
+        numa0, anc_pod, qused0, qruntime,
+        affdom0, affcount0, anticover0, prefrows0,
+        portused0, volfree0, volgrp0, imgrows0,
+    )
+    smem, full = pc.smem_spec, pc.full_spec
+    pod_spec = pc.pod_block_spec(R)
+    chosen, requested_t, qused_t = pl.pallas_call(
+        kernel,
+        grid=(P_pad // UNROLL,),
+        in_specs=(
+            [smem()] * 23
+            + [pod_spec] * 3
+            + [full((R, N))] * 4
+            + [full((1, N))] * 9
+            + [full((K * R, N)),
+               pl.BlockSpec((UNROLL, G_lane), lambda i: (i, 0)),
+               full((R, G_lane)), full((R, G_lane))]
+            + [full((T_eff, N))] * 3
+            + [full((S_eff, N))]
+            + [full((PT_eff, N)), full((1, N)), full((1, N)),
+               full((SI_eff, N))]
+        ),
+        out_specs=[
+            pc.chosen_block_spec(),
+            full((R, N)),
+            full((R, G_lane)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((R, N), jnp.float32),
+            jax.ShapeDtypeStruct((R, G_lane), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((R, N), jnp.float32),
+            pltpu.VMEM((R, N), jnp.float32),
+            pltpu.VMEM((K * R, N), jnp.float32),
+            pltpu.VMEM((1, N), jnp.float32),
+            pltpu.VMEM((R, N), jnp.float32),
+            pltpu.VMEM((R, G_lane), jnp.float32),
+            pltpu.VMEM((T_eff, N), jnp.float32),
+            pltpu.VMEM((T_eff, N), jnp.float32),
+            pltpu.VMEM((PT_eff, N), jnp.float32),
+            pltpu.VMEM((1, N), jnp.float32),
+            pltpu.SMEM((T_eff,), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+    )(*grid_inputs)
+    quota_used = qused_t[:, :G].T if G else fc.quota_used
+    return chosen[:P, 0], requested_t.T, quota_used
+
+
 def build_pallas_full_chain_step(args: LoadAwareArgs, num_gangs: int,
                                  num_groups: int, interpret: bool = False,
                                  jit: bool = True, active_axes=None,
@@ -496,238 +728,23 @@ def build_pallas_full_chain_step(args: LoadAwareArgs, num_gangs: int,
     """FullChainInputs -> (chosen[P], requested[N, R], quota_used[G, R]);
     same contract as models.full_chain.build_full_chain_step.
 
-    enable_volumes=False compiles OUT the CSI volume-limit machinery (the
-    per-pod [N] compare/select/update) — valid only for batches where no
-    pod mounts volumes; the backend selector checks the concrete inputs
-    and picks the variant."""
-    full_weights = args.weight_vector()
-    if active_axes is not None:
-        full_weights = full_weights[list(active_axes)]
-    weights = np.asarray(full_weights, np.float32)
-    prod_mode = args.score_according_prod_usage
+    enable_volumes=False compiles OUT the CSI volume-limit machinery
+    (pallas_full_chain_search) — valid only for batches where no pod
+    mounts volumes; the backend selector checks the concrete inputs and
+    picks the variant."""
 
     def full_chain_pallas(
             fc: FullChainInputs
     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        inputs = fc.base
-        P, R = inputs.fit_requests.shape
-        N = inputs.allocatable.shape[0]
-        K = fc.numa_free.shape[1]
-        G = fc.quota_used.shape[0]
-        G_eff = max(G, 1)
-        reject_np, reject_prod = la_ops.loadaware_node_reject(
-            inputs.allocatable,
-            inputs.la_filter_usage,
-            inputs.la_has_filter_usage,
-            inputs.la_filter_thresholds,
-            inputs.la_prod_thresholds,
-            inputs.la_prod_pod_usage,
-            inputs.la_filter_skip,
-        )
-        gang_pod_ok = jnp.where(
-            fc.gang_id >= 0, fc.gang_valid[jnp.maximum(fc.gang_id, 0)], True
-        )
-        # ancestor closure computed traceably (inputs may be tracers under jit):
-        # closure[g, a] = 1 iff a appears in g's chain (-1 padding never matches)
-        if G:
-            anc = jnp.any(
-                fc.quota_ancestors[:, :, None]
-                == jnp.arange(G, dtype=fc.quota_ancestors.dtype)[None, None, :],
-                axis=1,
-            ).astype(jnp.float32)
-        else:
-            anc = jnp.zeros((1, 1), jnp.float32)
-
-        f32, row = pc.f32, pc.row
-        P_pad, pad_p = pc.pad_pods(P, POD_BLOCK)
-        spad = lambda x: jnp.pad(f32(x), pad_p)  # noqa: E731
-
-        def pods_t(x):  # [P, R] -> [R, P_pad]
-            return jnp.pad(f32(x), pad_p + [(0, 0)]).T
-
-        # numa [N, K, R] -> [K*R, N]
-        numa0 = jnp.transpose(f32(fc.numa_free), (1, 2, 0)).reshape(K * R, N)
-        # quota lane axis padded to >= 128: Mosaic cannot truncate the narrow
-        # bool vectors that comparisons on a (R, G<128) block would produce.
-        # Padding runtime with +inf keeps phantom groups from ever violating.
-        G_lane = max(128, -(-G_eff // 128) * 128)
-        if G:
-            qused0 = jnp.pad(f32(fc.quota_used).T, [(0, 0), (0, G_lane - G)])
-            qruntime = jnp.pad(f32(fc.quota_runtime).T,
-                               [(0, 0), (0, G_lane - G)],
-                               constant_values=jnp.inf)
-            qid = jnp.asarray(fc.quota_id, jnp.int32)
-        else:
-            qused0 = jnp.zeros((R, G_lane), jnp.float32)
-            qruntime = jnp.full((R, G_lane), jnp.inf, jnp.float32)
-            qid = jnp.full(P, -1, jnp.int32)
-        # pre-gather each pod's ancestor-closure row: [P_pad, G_lane] in HBM,
-        # streamed as [UNROLL, G_lane] blocks (quota-less pods hit row 0 of
-        # an all-zeros closure or carry has_quota == False, so the row is
-        # never applied)
-        qid_pad = jnp.pad(qid, pad_p, constant_values=-1)
-        anc = jnp.pad(anc, [(0, 0), (0, G_lane - anc.shape[1])])
-        anc_pod = jnp.take(anc, jnp.maximum(qid_pad, 0), axis=0)
-
-        # inter-pod affinity: per-pod term rows become [P] f32 bitmasks
-        # (exact: T <= 24 < 2^24), node state transposes to [T, N]
-        T = fc.aff_dom.shape[1]
-        T_eff = max(T, 1)
-        pow_t = jnp.asarray(
-            [float(1 << t) for t in range(T)], jnp.float32)
-        if T:
-            def bitmask(rows):  # [P, T] bool -> [P_pad] f32
-                return jnp.pad(
-                    jnp.sum(f32(rows) * pow_t[None, :], axis=1), pad_p)
-
-            affreq_m = bitmask(fc.pod_aff_req)
-            antireq_m = bitmask(fc.pod_anti_req)
-            affmatch_m = bitmask(fc.pod_aff_match)
-            skew_i = jnp.asarray(fc.pod_spread_skew, jnp.int32)
-            skew0_m = bitmask((skew_i & 1) > 0)
-            skew1_m = bitmask((skew_i & 2) > 0)
-            skew2_m = bitmask((skew_i & 4) > 0)
-            affexists0 = f32(fc.aff_exists)
-            affdom0 = f32(fc.aff_dom).T
-            affcount0 = f32(fc.aff_count).T
-            anticover0 = f32(fc.anti_cover).T
-        else:
-            affreq_m = antireq_m = affmatch_m = jnp.zeros(P_pad, jnp.float32)
-            skew0_m = skew1_m = skew2_m = affreq_m
-            affexists0 = jnp.zeros(1, jnp.float32)
-            affdom0 = jnp.full((1, N), -1.0, jnp.float32)
-            affcount0 = jnp.zeros((1, N), jnp.float32)
-            anticover0 = jnp.zeros((1, N), jnp.float32)
-
-        # preference-less batches carry ZERO profile columns (snapshot emits
-        # true empties); the kernel skips the profile loops and the input
-        # slot gets one placeholder row
-        S = fc.pref_scores.shape[1]
-        S_eff = max(S, 1)
-        prefrows0 = (f32(fc.pref_scores).T if S
-                     else jnp.zeros((1, N), jnp.float32))
-        prefid_pad = jnp.pad(jnp.asarray(fc.pod_pref_id, jnp.int32), pad_p,
-                             constant_values=-1)
-        S2 = fc.ppref_w.shape[0] if T else 0  # zero rows == no profiles
-        pprefid_pad = jnp.pad(jnp.asarray(fc.pod_ppref_id, jnp.int32), pad_p,
-                              constant_values=-1)
-        pprefw0 = (f32(fc.ppref_w) if S2
-                   else jnp.zeros((1, max(T, 1)), jnp.float32))
-
-        # NodePorts slots as per-pod f32 bitmasks (PT <= 16 < 2^24, exact),
-        # node state transposed [PT, N]; volume headroom as one [1, N] row;
-        # ImageLocality rows like the preference profiles
-        PT = fc.port_used.shape[1]
-        PT_eff = max(PT, 1)
-        if PT:
-            pow_s = jnp.asarray(
-                [float(1 << s) for s in range(PT)], jnp.float32)
-            portwants_m = jnp.pad(jnp.sum(
-                f32(fc.pod_port_wants) * pow_s[None, :], axis=1), pad_p)
-            portused0 = f32(fc.port_used).T
-        else:
-            portwants_m = jnp.zeros(P_pad, jnp.float32)
-            portused0 = jnp.zeros((1, N), jnp.float32)
-        VG = fc.vol_needed.shape[1]
-        if enable_volumes:
-            volneeded_pad = jnp.pad(
-                f32(fc.vol_needed), pad_p + [(0, 0)]).reshape(-1)
-        else:
-            # volume machinery compiled out: the kernel never reads the
-            # ref, so a 1-float placeholder keeps high-VG volume-less
-            # batches inside the SMEM budget
-            volneeded_pad = jnp.zeros(1, jnp.float32)
-        volfree0 = f32(fc.vol_free)[None, :]
-        volgrp0 = f32(fc.node_vol_group)[None, :]
-        SI = fc.img_scores.shape[1]
-        SI_eff = max(SI, 1)
-        imgrows0 = (f32(fc.img_scores).T if SI
-                    else jnp.zeros((1, N), jnp.float32))
-        imgid_pad = jnp.pad(jnp.asarray(fc.pod_img_id, jnp.int32), pad_p,
-                            constant_values=-1)
-
-        kernel = _make_kernel(weights, prod_mode, N, R, K, G_eff, T, S, S2,
-                              PT, SI, VOL=enable_volumes, VG=VG,
-                              BAL=resolve_balance_idx(active_axes))
-        grid_inputs = (
-            spad(inputs.is_prod), spad(inputs.pod_valid),
-            spad(inputs.is_daemonset), spad(gang_pod_ok),
-            spad(fc.needs_numa), spad(fc.needs_bind),
-            spad(fc.full_pcpus), spad(fc.cores_needed),
-            jnp.pad(f32(fc.pod_taint_mask), pad_p, constant_values=1.0),
-            affreq_m, antireq_m, affmatch_m,
-            skew0_m, skew1_m, skew2_m, affexists0,
-            prefid_pad, pprefid_pad, pprefw0,
-            portwants_m, volneeded_pad, imgid_pad,
-            qid_pad,
-            pods_t(inputs.fit_requests), pods_t(fc.requests),
-            pods_t(inputs.estimated),
-            f32(inputs.allocatable).T, f32(inputs.requested).T,
-            f32(inputs.la_term_nonprod).T, f32(inputs.la_term_prod).T,
-            row(~reject_np), row(~reject_prod),
-            row(inputs.node_ok), row(inputs.la_score_valid),
-            row(fc.has_topology), row(fc.bind_free), row(fc.cpus_per_core),
-            jnp.asarray(fc.numa_policy, jnp.int32)[None, :],
-            jnp.exp2(f32(fc.node_taint_group))[None, :],
-            numa0, anc_pod, qused0, qruntime,
-            affdom0, affcount0, anticover0, prefrows0,
-            portused0, volfree0, volgrp0, imgrows0,
-        )
-        smem, full = pc.smem_spec, pc.full_spec
-        pod_spec = pc.pod_block_spec(R)
-        chosen, requested_t, qused_t = pl.pallas_call(
-            kernel,
-            grid=(P_pad // UNROLL,),
-            in_specs=(
-                [smem()] * 23
-                + [pod_spec] * 3
-                + [full((R, N))] * 4
-                + [full((1, N))] * 9
-                + [full((K * R, N)),
-                   pl.BlockSpec((UNROLL, G_lane), lambda i: (i, 0)),
-                   full((R, G_lane)), full((R, G_lane))]
-                + [full((T_eff, N))] * 3
-                + [full((S_eff, N))]
-                + [full((PT_eff, N)), full((1, N)), full((1, N)),
-                   full((SI_eff, N))]
-            ),
-            out_specs=[
-                pc.chosen_block_spec(),
-                full((R, N)),
-                full((R, G_lane)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
-                jax.ShapeDtypeStruct((R, N), jnp.float32),
-                jax.ShapeDtypeStruct((R, G_lane), jnp.float32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((R, N), jnp.float32),
-                pltpu.VMEM((R, N), jnp.float32),
-                pltpu.VMEM((K * R, N), jnp.float32),
-                pltpu.VMEM((1, N), jnp.float32),
-                pltpu.VMEM((R, N), jnp.float32),
-                pltpu.VMEM((R, G_lane), jnp.float32),
-                pltpu.VMEM((T_eff, N), jnp.float32),
-                pltpu.VMEM((T_eff, N), jnp.float32),
-                pltpu.VMEM((PT_eff, N), jnp.float32),
-                pltpu.VMEM((1, N), jnp.float32),
-                pltpu.SMEM((T_eff,), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
-            ),
-            interpret=interpret,
-        )(*grid_inputs)
-        chosen = chosen[:P, 0]
+        chosen, requested, quota_used = pallas_full_chain_search(
+            fc, args, active_axes=active_axes,
+            enable_volumes=enable_volumes, interpret=interpret)
 
         # ---- Permit barrier (XLA post-pass, once per batch)
         keep = gang_permit_mask(
             chosen, fc.gang_id, fc.gang_min_member, fc.gang_assumed,
             fc.gang_group_id, num_gangs, num_groups,
         )
-        chosen = jnp.where(keep, chosen, -1)
-        quota_used = qused_t[:, :G].T if G else fc.quota_used
-        return chosen, requested_t.T, quota_used
+        return jnp.where(keep, chosen, -1), requested, quota_used
 
     return jax.jit(full_chain_pallas) if jit else full_chain_pallas

@@ -149,6 +149,7 @@ def build_sharded_fused_wave_step(
         build_fused_wave_step,
     )
 
+    # no VMEM budget: the XLA search pass (build_sharded_chained_wave_step)
     raw = build_fused_wave_step(
         args, num_gangs, num_groups, waves=waves, jit=False,
         active_axes=active_axes, explain=explain,
@@ -250,6 +251,8 @@ def build_sharded_chained_wave_step(
         build_chained_wave_step,
     )
 
+    # no VMEM budget: the search pass stays on the XLA evaluator, since
+    # a pallas_call does not partition over the node-sharded carry
     raw = build_chained_wave_step(
         args, num_gangs, num_groups, jit=False,
         active_axes=active_axes, explain=explain,

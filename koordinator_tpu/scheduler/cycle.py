@@ -60,6 +60,7 @@ from koordinator_tpu.models.fused_waves import (
     ResSides,
     WaveSideInputs,
     initial_wave_carry,
+    wave_search_backend,
 )
 from koordinator_tpu.obs import Tracer
 from koordinator_tpu.ops.volumes import (
@@ -1329,15 +1330,33 @@ class Scheduler:
             if isinstance(t, ScoreTransformer)
             and getattr(t, "device_pass", None) is not None)
 
+    def _wave_search(self, fc_host, explain, claims: bool
+                     ) -> Tuple[str, bool]:
+        """The wave body's search-pass kernel for this batch
+        (models/fused_waves.wave_search_backend: "pallas" or "xla") and
+        the volume flag the wave step is built with, a part of its
+        step-cache key. The flag is read from the host arrays before
+        upload, as the single-round selector reads it. The mesh keeps
+        the XLA pass: a pallas_call does not partition over the
+        node-sharded carry. Where the pass stays XLA the flag is fixed,
+        so it never splits the cache."""
+        if self.mesh is not None or self._vmem_budget is None:
+            return "xla", True
+        vol = bool((np.asarray(fc_host.vol_needed) > 0).any())
+        backend = wave_search_backend(fc_host, self._vmem_budget, vol,
+                                      explain, claims)
+        return backend, (vol if backend == "pallas" else True)
+
     def _get_fused_step(self, signature: Tuple, ng: int, ngroups: int,
                         active, waves: int, explain=None,
-                        sides_tag: Tuple = (0, 0)) -> object:
+                        sides_tag: Tuple = (0, 0),
+                        volumes: bool = True) -> object:
         from koordinator_tpu.models.fused_waves import build_fused_wave_step
 
         nc, nres = sides_tag
         key = ("fused", waves, signature, ng, ngroups, tuple(active),
                explain, self._mesh_tag(), sides_tag,
-               self._score_pass_tag())
+               self._score_pass_tag(), volumes)
         with self._step_lock:
             step = self._step_cache.get(key)
         if step is not None:
@@ -1361,7 +1380,8 @@ class Scheduler:
                 step = build_fused_wave_step(
                     self.args, ng, ngroups, waves=waves, active_axes=active,
                     explain=explain, prod=prod, claims=nc > 0,
-                    res=nres > 0, score_passes=passes)
+                    res=nres > 0, score_passes=passes,
+                    vmem_budget_bytes=self._vmem_budget, volumes=volumes)
         self._add_compile_wall(csp.duration_seconds)
         with self._step_lock:
             self._step_cache[key] = step
@@ -1369,7 +1389,8 @@ class Scheduler:
 
     def _get_chain_step(self, signature: Tuple, ng: int, ngroups: int,
                         active, explain=None,
-                        sides_tag: Tuple = (0, 0)) -> object:
+                        sides_tag: Tuple = (0, 0),
+                        volumes: bool = True) -> object:
         """The chained per-wave step (overlapped replay). NOTE: no wave
         depth in the cache key — one compiled program serves every K,
         which also collapses the fused path's per-K compile fan-out."""
@@ -1379,7 +1400,8 @@ class Scheduler:
 
         nc, nres = sides_tag
         key = ("chain", signature, ng, ngroups, tuple(active), explain,
-               self._mesh_tag(), sides_tag, self._score_pass_tag())
+               self._mesh_tag(), sides_tag, self._score_pass_tag(),
+               volumes)
         with self._step_lock:
             step = self._step_cache.get(key)
         if step is not None:
@@ -1403,7 +1425,8 @@ class Scheduler:
                 step = build_chained_wave_step(
                     self.args, ng, ngroups, active_axes=active,
                     explain=explain, prod=prod, claims=nc > 0,
-                    res=nres > 0, score_passes=passes)
+                    res=nres > 0, score_passes=passes,
+                    vmem_budget_bytes=self._vmem_budget, volumes=volumes)
         self._add_compile_wall(csp.duration_seconds)
         with self._step_lock:
             self._step_cache[key] = step
@@ -3125,17 +3148,19 @@ class Scheduler:
             explain = self._effective_explain()
             ex_out = None
             try:
+                backend, volumes = self._wave_search(
+                    fc_host, explain, claims=res_ctx["tag"][0] > 0)
                 step = self._get_fused_step(
                     (pods.padded_size, nodes.padded_size,
                      fc_host.quota_runtime.shape[0]),
                     ng, ngroups, active, k_waves, explain=explain,
-                    sides_tag=res_ctx["tag"],
+                    sides_tag=res_ctx["tag"], volumes=volumes,
                 )
                 with self.tracer.span(
                         "kernel",
                         compiled="1" if self._last_step_compiled else "0",
                         waves=str(k_waves),
-                        shards=self._dispatch_shards(),
+                        shards=self._dispatch_shards(), backend=backend,
                         decision_id=win.decision_id) as ksp:
                     fc = fc_host
                     up_fields = side_fields
@@ -3155,7 +3180,8 @@ class Scheduler:
                                  fc_host.quota_runtime.shape[0]),
                                 ng, ngroups, active, explain,
                                 waves=int(k_waves),
-                                sides_tag=list(res_ctx["tag"])),
+                                sides_tag=list(res_ctx["tag"]),
+                                volumes=volumes),
                             (fc, sides, np.int32(len(nodes.names)))
                             if explain is not None else (fc, sides))
                     win.mark_dispatch(self._window_path("fused"))
@@ -3198,6 +3224,7 @@ class Scheduler:
                             self.device_snapshot.end_dispatch()
                     scheduler_metrics.WAVES_PER_DISPATCH.observe(
                         float(waves_run))
+                    scheduler_metrics.WAVE_DISPATCHES.inc(backend=backend)
                     scheduler_metrics.READBACK_BYTES.inc(
                         int(bind_pods.nbytes + bind_nodes.nbytes
                             + bind_zones.nbytes + wave_counts.nbytes + 4))
@@ -3596,17 +3623,21 @@ class Scheduler:
         while True:
             explain = self._effective_explain()
             try:
+                backend, volumes = self._wave_search(
+                    fc_host, explain, claims=res_ctx["tag"][0] > 0)
                 step = self._get_chain_step(
                     (pods.padded_size, nodes.padded_size,
                      fc_host.quota_runtime.shape[0]),
                     ng, ngroups, active, explain=explain,
-                    sides_tag=res_ctx["tag"],
+                    sides_tag=res_ctx["tag"], volumes=volumes,
                 )
+                # backend: the search pass's kernel (pallas / xla), as
+                # the single-round kernel span carries its step's
                 with self.tracer.span(
                         "kernel",
                         compiled="1" if self._last_step_compiled else "0",
                         waves=str(k_waves), overlap="1",
-                        shards=self._dispatch_shards(),
+                        shards=self._dispatch_shards(), backend=backend,
                         decision_id=win.decision_id):
                     fc = fc_host
                     up_fields = side_fields
@@ -3635,7 +3666,8 @@ class Scheduler:
                                 (pods.padded_size, nodes.padded_size,
                                  fc_host.quota_runtime.shape[0]),
                                 ng, ngroups, active, explain,
-                                sides_tag=list(res_ctx["tag"])),
+                                sides_tag=list(res_ctx["tag"]),
+                                volumes=volumes),
                             (fc, carry, sides, np.int32(n_real))
                             if explain is not None
                             else (fc, carry, sides))
@@ -3695,6 +3727,8 @@ class Scheduler:
         if chain_compiled:
             self._add_compile_wall(window_seconds)
         scheduler_metrics.WAVES_PER_DISPATCH.observe(float(executed))
+        scheduler_metrics.WAVE_DISPATCHES.inc(float(executed),
+                                              backend=backend)
         # the timeline window closes at the chain's LAST device sync —
         # the same dispatch->last-sync quantity the kernel span measures
         self._close_window(win, attempts, had_deadline, level0,

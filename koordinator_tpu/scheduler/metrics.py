@@ -72,6 +72,14 @@ WAVES_PER_DISPATCH = REGISTRY.histogram(
     "Scheduling waves executed per device dispatch",
     buckets=(1.0, 2.0, 4.0, 8.0),
 )
+# which kernel ran the wave body's search pass (models/fused_waves.py
+# wave_search_backend): pallas / xla, one count per device program that
+# runs waves (a chained wave each, a fused while_loop once)
+WAVE_DISPATCHES = REGISTRY.counter(
+    "koord_scheduler_wave_dispatches_total",
+    "Device dispatches of fused-wave programs, labeled by the search "
+    "pass's kernel (backend)",
+)
 READBACK_BYTES = REGISTRY.counter(
     "koord_scheduler_readback_bytes_total",
     "Bytes read back from the device across all kernel dispatches",

@@ -446,11 +446,13 @@ class WarmupRunner:
             step = sched._get_fused_step(
                 signature, meta["ng"], meta["ngroups"], active,
                 meta["waves"], explain=explain,
-                sides_tag=tuple(meta["sides_tag"]))
+                sides_tag=tuple(meta["sides_tag"]),
+                volumes=meta.get("volumes", True))
         elif kind == "chain":
             step = sched._get_chain_step(
                 signature, meta["ng"], meta["ngroups"], active,
-                explain=explain, sides_tag=tuple(meta["sides_tag"]))
+                explain=explain, sides_tag=tuple(meta["sides_tag"]),
+                volumes=meta.get("volumes", True))
         else:
             return "skipped"
         args = [zeros_from_spec(s) for s in entry["args"]]

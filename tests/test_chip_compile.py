@@ -6,7 +6,8 @@ chip allows is refused here, at no chip time. These tests pin that the
 Pallas selectors never route a shape to a kernel the v5e compiler refuses
 (ops/pallas_common.VMEM_LIMIT_BYTES, the estimate_vmem_bytes bounds), that
 BASELINE config 4 (10,240 x 5,120 padded) stays on Pallas, and that the
-LoadAware Pallas step and the chained wave step compile at that shape,
+LoadAware Pallas step and the chained wave step (its search pass on the
+Pallas kernel) compile at that shape,
 and that the sharded steps the size rule picks for the mesh-50k cell
 (50,000 nodes, 57,344 padded, over a 2x2 host) compile with their
 cross-chip collectives.
@@ -168,12 +169,17 @@ def test_loadaware_pallas_step_compiles_at_10k_x_5k(template, one_chip):
 
 
 def test_chained_wave_step_compiles_at_10k_x_5k(template, one_chip):
+    """The chained wave program at the backlog cell's padded shape, built
+    as the scheduler builds it on a v5e (its VMEM budget, a volume-less
+    batch): the search pass is the Pallas full-chain kernel's custom
+    call inside the wave program."""
     import jax
 
     from koordinator_tpu.models.fused_waves import (
         build_chained_wave_step,
         initial_wave_carry,
         plain_sides,
+        wave_search_backend,
     )
     from koordinator_tpu.ops.loadaware import LoadAwareArgs
 
@@ -184,9 +190,13 @@ def test_chained_wave_step_compiles_at_10k_x_5k(template, one_chip):
     sides = plain_sides(nr, nr)
     carry = _on_chip(jax.eval_shape(initial_wave_carry, fc_s, sides),
                      one_chip)
+    assert wave_search_backend(fc_s, _v5e_budget(), False) == "pallas"
     step = build_chained_wave_step(LoadAwareArgs(), ng, ngroups, jit=False,
-                                   active_axes=tuple(range(R)))
-    jax.jit(step).lower(fc_s, carry, sides).compile()
+                                   active_axes=tuple(range(R)),
+                                   vmem_budget_bytes=_v5e_budget(),
+                                   volumes=False)
+    text = jax.jit(step).lower(fc_s, carry, sides).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 @pytest.fixture(scope="module")
@@ -262,3 +272,5 @@ def test_sharded_chained_wave_step_compiles_at_mesh_50k(mesh_50k):
                                            mesh, active_axes=(0, 1, 2))
     text = step.lower(fc_s, carry, sides).compile().as_text()
     assert "all-reduce" in text
+    # the sharded chain keeps the XLA search pass: no Pallas call
+    assert "tpu_custom_call" not in text
